@@ -27,10 +27,11 @@ Output rows (all byte figures are bytes; rows carry ``arena_bytes`` and
                                      (use_pallas=True; int8 graphs only)
     executor.<case>.pallas_speedup_x default-lowering warm / pallas warm
 
-The MobileNet@192 cases run in a fresh subprocess (``python -m
-benchmarks.bench_executor``): earlier benchmarks in the same process warm
-jax's eager-dispatch caches for exactly these shapes, which would silently
-turn the first-call measurement into a warm one.
+On the CPU (``JAX_PLATFORMS=cpu``) the MobileNet@192 cases run in a fresh
+subprocess (``python -m benchmarks.bench_executor``): earlier benchmarks
+in the same process warm jax's eager-dispatch caches for exactly these
+shapes, which would silently turn the first-call measurement into a warm
+one.  On a TPU they run in the calling process, which holds the chip.
 
 Smoke mode (REPRO_BENCH_SMOKE=1, set by ``run.py --smoke``) keeps only the
 small graphs so CI stays fast.
@@ -172,6 +173,10 @@ def run(report):
     # use_pallas=True compile + bit-identity path
     _pallas_case(report, "mobilenet_025_96_int8", _quantized_mobilenet())
     if _SMOKE:
+        return
+    from repro.serving import cpu_platform_requested
+    if not cpu_platform_requested():
+        _headline_cases(report)
         return
     # fresh process: see module docstring
     proc = subprocess.run([sys.executable, "-m", "benchmarks.bench_executor"],

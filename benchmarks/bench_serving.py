@@ -5,7 +5,7 @@ The comparison is **weak scaling at fixed per-replica lanes**: the
 single-device ``GraphServingEngine`` dispatches ``lanes`` vmap lanes per
 XLA call; the ``ShardedServingEngine`` dispatches ``replicas x lanes``
 lanes per call across a replica mesh of forced host devices
-(``--xla_force_host_platform_device_count``, SNIPPETS.md Snippets 2-3).
+(``--xla_force_host_platform_device_count``, SNIPPETS.md Snippet 2).
 Per-dispatch work per replica is identical, so with >= ``replicas`` real
 cores the sharded engine's requests/s scales with the mesh while per
 -request p50/p99 stays at single-device levels; on fewer cores the
@@ -22,10 +22,12 @@ deterministic ``arena_bytes`` of the deployment (strict bytes gate).
 Outputs are checked bit-identical to one-shot ``Deployment.run`` before
 any timing is reported.
 
-The whole benchmark runs in a fresh subprocess: the replica mesh only
-exists if XLA_FLAGS is set before the first jax import, which the parent
-(run.py) process has long since done.  ``REPRO_SERVING_DEVICES`` sets the
-mesh size (default 4; the CI smoke row uses 2).
+On the CPU (``JAX_PLATFORMS=cpu``) the whole benchmark runs in a fresh
+subprocess: the replica mesh of virtual host devices only exists if
+XLA_FLAGS is set before the first jax import, which the parent (run.py)
+process has long since done.  ``REPRO_SERVING_DEVICES`` sets the mesh size
+(default 4; the CI smoke row uses 2).  On a TPU it runs in the calling
+process, over the chips that process holds (a child could not get them).
 
 Smoke mode (REPRO_BENCH_SMOKE=1): MobileNet-0.25@96 int8 only.  Full mode
 adds the headline MobileNet-1.0@192 int8 deployment and, when the host
@@ -42,8 +44,13 @@ _ROW_TAG = "SERVINGROW "
 
 
 # --------------------------------------------------------- subprocess side
+def _print_row(name, us, derived, **meta):
+    print(_ROW_TAG + json.dumps(
+        {"name": name, "us": us, "derived": derived, "meta": meta}))
+
+
 def _bench_case(case: str, graph, qmodel, *, replicas: int, lanes: int,
-                n_requests: int, use_pallas: bool):
+                n_requests: int, use_pallas: bool, emit=_print_row):
     import numpy as np
 
     import repro.deploy as deploy
@@ -76,9 +83,7 @@ def _bench_case(case: str, graph, qmodel, *, replicas: int, lanes: int,
     meta = dict(arena_bytes=d.arena_bytes, dtypes="int8")
 
     def row(name, us, derived, **extra):
-        print(_ROW_TAG + json.dumps(
-            {"name": name, "us": us, "derived": derived,
-             "meta": {**meta, **extra}}))
+        emit(name, us, derived, **meta, **extra)
 
     # expired/shed ride on the *_rps rows and are gated exactly zero by
     # compare.py: this is the no-fault configuration, so any nonzero count
@@ -96,14 +101,16 @@ def _bench_case(case: str, graph, qmodel, *, replicas: int, lanes: int,
     return speedup
 
 
-def _main():
+def _main(emit=_print_row, on_host: bool = True):
     replicas = int(os.environ.get("REPRO_SERVING_DEVICES", "4"))
     import jax
 
     from repro.graphs import mobilenet_v1_graph, quantize_graph, random_input
 
     have = jax.local_device_count()
-    if have < replicas:
+    if not on_host:             # real chips: as many replicas as there are
+        replicas = min(replicas, have)
+    elif have < replicas:
         raise SystemExit(f"forced host mesh missing: {have} devices, "
                          f"wanted {replicas} (XLA_FLAGS not set pre-init?)")
 
@@ -111,7 +118,8 @@ def _main():
     q = quantize_graph(g, random_input(g))
     t0 = time.time()
     _bench_case("mobilenet_025_96_int8", g, q, replicas=replicas,
-                lanes=2, n_requests=8 * replicas, use_pallas=True)
+                lanes=2, n_requests=8 * replicas, use_pallas=True,
+                emit=emit)
     print(f"# smoke case done in {time.time() - t0:.1f}s", file=sys.stderr)
     if _SMOKE:
         return
@@ -119,10 +127,12 @@ def _main():
     q = quantize_graph(g, random_input(g))
     speedup = _bench_case("mobilenet_100_192_int8", g, q,
                           replicas=replicas, lanes=2,
-                          n_requests=4 * replicas, use_pallas=True)
-    # the scale-out bar is physical: replicas can only run concurrently
-    # on >= that many cores.  Time-shared hosts report, but don't gate.
-    if (os.cpu_count() or 1) >= replicas:
+                          n_requests=4 * replicas, use_pallas=True,
+                          emit=emit)
+    # the scale-out bar is physical: host replicas can only run
+    # concurrently on >= that many cores.  Time-shared hosts report, but
+    # don't gate; neither do chips, which this benchmark does not time.
+    if on_host and (os.cpu_count() or 1) >= replicas:
         assert speedup >= 2.0, (
             f"sharded engine only {speedup:.2f}x over single-device "
             f"({replicas} replicas on {os.cpu_count()} cores)")
@@ -130,8 +140,13 @@ def _main():
 
 # ------------------------------------------------------------- parent side
 def run(report):
-    """Spawn the benchmark in a fresh process with the replica mesh forced
-    (2 devices in smoke mode, 4 otherwise), and re-report its rows."""
+    """On the CPU, spawn the benchmark in a fresh process with the replica
+    mesh forced (2 devices in smoke mode, 4 otherwise) and re-report its
+    rows; anywhere else run it here, on this process's devices."""
+    from repro.serving import cpu_platform_requested
+    if not cpu_platform_requested():
+        _main(emit=report, on_host=False)
+        return
     env = dict(os.environ)
     env.setdefault("REPRO_SERVING_DEVICES", "2" if _SMOKE else "4")
     src = os.path.join(os.path.dirname(__file__), os.pardir, "src")

@@ -138,6 +138,10 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     if args.smoke:
         os.environ["REPRO_BENCH_SMOKE"] = "1"
+    from repro.compile_cache import enable_compile_cache
+    # children (bench_serving's host mesh) find the same cache through
+    # the variable JAX reads itself
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = enable_compile_cache()
 
     from . import (bench_figure1, bench_table1, bench_scheduler,
                    bench_jaxpr, bench_kernels, bench_pex, bench_roofline,

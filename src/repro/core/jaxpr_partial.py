@@ -193,7 +193,7 @@ def _expand_run(eqns: Sequence, k: int) -> List:
     idx_aval = ShapedArray((), np.dtype("int32"))
     res: List = []
     zero = Literal(np.zeros((), odtype), ShapedArray((), odtype))
-    acc: object = Var("", acc_aval)
+    acc: object = Var(acc_aval)
     res.append(new_jaxpr_eqn(
         [zero], [acc], lax_internal.broadcast_in_dim_p,
         dict(shape=oshape, broadcast_dimensions=(), sharding=None),
@@ -216,8 +216,8 @@ def _expand_run(eqns: Sequence, k: int) -> List:
                 key = (id(v), a, b)
                 if key not in ext_slices:
                     vshape = tuple(v.aval.shape)
-                    sv = Var("", ShapedArray((b - a,) + vshape[1:],
-                                             v.aval.dtype))
+                    sv = Var(ShapedArray((b - a,) + vshape[1:],
+                                         v.aval.dtype))
                     res.append(new_jaxpr_eqn(
                         [v], [sv], lax_slicing.slice_p,
                         dict(start_indices=(a,) + (0,) * (len(vshape) - 1),
@@ -226,12 +226,12 @@ def _expand_run(eqns: Sequence, k: int) -> List:
                     ext_slices[key] = sv
                 ins.append(ext_slices[key])
             o = eqn.outvars[0]
-            co = Var("", ShapedArray((b - a,) + tuple(o.aval.shape)[1:],
-                                     o.aval.dtype))
+            co = Var(ShapedArray((b - a,) + tuple(o.aval.shape)[1:],
+                                 o.aval.dtype))
             res.append(new_jaxpr_eqn(ins, [co], eqn.primitive,
                                      dict(eqn.params), no_effects, _src()))
             clone_out[d] = co
-        nxt = out if s == k - 1 else Var("", acc_aval)
+        nxt = out if s == k - 1 else Var(acc_aval)
         idx = [Literal(np.int32(a), idx_aval)] + [
             Literal(np.int32(0), idx_aval)] * (len(oshape) - 1)
         res.append(new_jaxpr_eqn(

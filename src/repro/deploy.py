@@ -248,7 +248,8 @@ def build(graph: Graph, *, arena_budget: Optional[int] = None,
     * ``quantize`` — post-training-quantize a float graph to int8 first
       (``calibration``: input dict(s); default = deterministic synthetic).
     * ``use_pallas`` — route int8 convs through the fused Pallas kernels
-      (bit-identical; DESIGN.md §9).
+      (bit-identical; DESIGN.md §9).  They compile with Mosaic on a TPU
+      and run through the Pallas interpreter elsewhere.
     * ``objective`` — ``"memory"`` (lowest peak) or ``"latency"``
       (cheapest in-budget schedule; needs ``arena_budget``).
     * ``macs_cap`` — max halo-recompute extra-MACs fraction.

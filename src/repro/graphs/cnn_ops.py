@@ -20,23 +20,22 @@ Each builder attaches a ``SliceSpec`` for the sliceable kinds; the spec's
 from __future__ import annotations
 
 import math
+import zlib
 from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
-try:  # jnp when available (tests run it through jax), numpy otherwise
-    import jax.numpy as jnp
-    from jax import lax
-    _HAVE_JAX = True
-except Exception:  # pragma: no cover
-    _HAVE_JAX = False
+import jax.numpy as jnp
+from jax import lax
 
 from repro.core.graph import Graph, Operator
 from repro.core.partition import PEX_ATTR, SliceSpec, same_pads
 
 
 def _weight(name: str, shape: Tuple[int, ...], scale: float = 0.1):
-    rng = np.random.default_rng(abs(hash(name)) % (2 ** 32))
+    # crc32, not hash(): str hashes are salted per process, which made
+    # every process draw different weights
+    rng = np.random.default_rng(zlib.crc32(name.encode()))
     return (rng.standard_normal(shape) * scale).astype(np.float32)
 
 
@@ -226,14 +225,26 @@ class CNNBuilder:
         wgt = _weight(f"fc{self._n + 1}_w", (h * w * c, nout))
 
         def fn(a, w=wgt):
-            # explicit mul+reduce instead of a dot: XLA CPU emits tiny dots
-            # context-sensitively (surrounding fusion changes the
-            # accumulation path), which would break the compiled executor's
-            # bit-identity contract with this eager reference.
-            return jnp.sum(jnp.reshape(a, (-1, 1)) * w, axis=0)[None, None, :]
+            # products, then a fixed pairwise tree of adds — not a dot or a
+            # fused mul+reduce, whose accumulation order (and FMA
+            # contraction) XLA CPU picks per context: eager, jitted and
+            # vmapped runs must round identically for the compiled
+            # executor's bit-identity contract with this eager reference.
+            p = lax.optimization_barrier(jnp.reshape(a, (-1, 1)) * w)
+            return _tree_sum(p)[None, None, :]
 
         return self._emit("fc", [x], (1, 1, nout), fn, weight=wgt,
                           weight_bytes=wgt.nbytes)
+
+
+def _tree_sum(p):
+    """Sum over axis 0 as a fixed pairwise tree of elementwise adds."""
+    while p.shape[0] > 1:
+        h = p.shape[0] // 2
+        top = p[:h] + p[h:2 * h]
+        p = top if p.shape[0] % 2 == 0 else jnp.concatenate(
+            [top, p[2 * h:]], axis=0)
+    return p[0]
 
 
 def conv2d(x, w, stride: int, hpad: Optional[Tuple[int, int]] = None,

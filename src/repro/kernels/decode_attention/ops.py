@@ -2,21 +2,14 @@
 from __future__ import annotations
 
 from functools import partial
-from typing import Optional
 
 import jax
 
 from .kernel import decode_attention_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @partial(jax.jit, static_argnames=("bs", "interpret"))
 def decode_attention(q, k_cache, v_cache, lengths, *, bs: int = 256,
-                     interpret: Optional[bool] = None):
-    if interpret is None:
-        interpret = not _on_tpu()
+                     interpret: bool = False):
     return decode_attention_pallas(q, k_cache, v_cache, lengths, bs=bs,
                                    interpret=interpret)

@@ -178,19 +178,14 @@ def run_combo(arch: str, shape_name: str, mesh_kind: str, out_dir: str,
     if mem is not None:
         for f in ("argument_size_in_bytes", "output_size_in_bytes",
                   "temp_size_in_bytes", "generated_code_size_in_bytes",
-                  "alias_size_in_bytes", "peak_memory_in_bytes"):
-            v = getattr(mem, f, None)
-            if v is not None:
-                mem_d[f] = int(v)
-        if "peak_memory_in_bytes" not in mem_d and mem_d:
-            # newer jaxlibs dropped the field; derive the standard proxy so
-            # the artifact schema stays stable for downstream aggregation
-            mem_d["peak_memory_in_bytes"] = max(
-                0, mem_d.get("argument_size_in_bytes", 0)
-                + mem_d.get("output_size_in_bytes", 0)
-                + mem_d.get("temp_size_in_bytes", 0)
-                - mem_d.get("alias_size_in_bytes", 0))
-            mem_d["peak_memory_is_derived"] = 1
+                  "alias_size_in_bytes"):
+            mem_d[f] = int(getattr(mem, f))
+        # XLA reports no peak; the standard proxy keeps the schema stable
+        mem_d["peak_memory_in_bytes"] = max(
+            0, mem_d["argument_size_in_bytes"]
+            + mem_d["output_size_in_bytes"] + mem_d["temp_size_in_bytes"]
+            - mem_d["alias_size_in_bytes"])
+        mem_d["peak_memory_is_derived"] = 1
 
     # ---- analysis pass: re-lower with layer/chunk scans UNROLLED so that
     # cost_analysis and the HLO collective census count every iteration
@@ -209,8 +204,6 @@ def run_combo(arch: str, shape_name: str, mesh_kind: str, out_dir: str,
         finally:
             runtime.UNROLL_SCANS = False
     cost = a_compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):   # older jaxlib: [dict] per program
-        cost = cost[0] if cost else {}
     flops = float(cost.get("flops", 0.0))
     bytes_acc = float(cost.get("bytes accessed", 0.0))
     hlo = a_compiled.as_text()

@@ -23,11 +23,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices, have {len(devices)} — the dry-run must set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             f"any jax import")
-    try:
-        return jax.make_mesh(shape, axes, devices=devices[:n])
-    except TypeError:  # older make_mesh without devices kwarg
-        from jax.sharding import Mesh
-        return Mesh(np.asarray(devices[:n]).reshape(shape), axes)
+    return jax.make_mesh(shape, axes, devices=devices[:n])
 
 
 def make_local_mesh(model_parallel: int = 1):
@@ -36,10 +32,4 @@ def make_local_mesh(model_parallel: int = 1):
     devs = jax.devices()
     m = min(model_parallel, len(devs))
     d = len(devs) // m
-    try:
-        return jax.make_mesh((d, m), ("data", "model"),
-                             devices=devs[:d * m])
-    except TypeError:
-        from jax.sharding import Mesh
-        return Mesh(np.asarray(devs[:d * m]).reshape(d, m),
-                    ("data", "model"))
+    return jax.make_mesh((d, m), ("data", "model"), devices=devs[:d * m])

@@ -60,23 +60,6 @@ from jax import lax
 from repro.core.allocator import ArenaPlan, ArenaPlanner
 from repro.core.graph import Graph, Operator
 
-# ``optimization_barrier`` (the fence strict mode places between operators,
-# see ``compile_schedule``) has no vmap batching rule in this jax version,
-# which would break micro-batched serving (vmap over stacked arenas).  The
-# barrier is semantically the identity, so batching is a pass-through.
-try:  # pragma: no cover - exercised via serving vmap tests
-    from jax._src.lax.lax import optimization_barrier_p
-    from jax.interpreters import batching
-
-    if optimization_barrier_p not in batching.primitive_batchers:
-        def _optimization_barrier_batcher(args, dims, **params):
-            return optimization_barrier_p.bind(*args, **params), dims
-
-        batching.primitive_batchers[optimization_barrier_p] = \
-            _optimization_barrier_batcher
-except Exception:            # private path moved: only fuse=True vmaps
-    pass
-
 
 # ------------------------------------------------------------- dtype bitcasts
 # The arena is bytes; tensors are typed views of byte ranges.  These two
@@ -122,7 +105,7 @@ class LoweringCtx:
 
     graph: Graph
     use_pallas: bool = False
-    interpret: Optional[bool] = None   # Pallas interpret override (None=auto)
+    interpret: bool = False     # Pallas interpreter instead of Mosaic
 
     def shape(self, tensor: str) -> Tuple[int, ...]:
         t = self.graph.tensors[tensor]
@@ -465,6 +448,16 @@ def _plan_items(ctx: LoweringCtx, offsets: Dict[str, Tuple[int, int]],
 
 
 # ------------------------------------------------------------------- executor
+def _placed(f: Callable, device) -> Callable:
+    """``f`` with its arena argument moved to ``device`` first (a no-op
+    when it already lives there), so the jitted program runs there.
+    ``.jitted`` is ``f`` itself, for ``lower``/``compile``."""
+    def run(arena):
+        return f(jax.device_put(arena, device))
+    run.jitted = f
+    return run
+
+
 @dataclasses.dataclass
 class CompiledExecutor:
     """A scheduled graph lowered to one jitted arena program.
@@ -474,6 +467,11 @@ class CompiledExecutor:
     donated-argument form.  The arena is **uint8**: ``arena_size`` equals
     ``plan.arena_size`` bytes, and the program never reads or writes past
     it.  Tensors are typed bitcast views of their placements.
+
+    ``device`` is where the program runs: ``fn`` and ``batched_fn`` place
+    their (host-built) arenas there before executing.  ``interpret``
+    records whether its Pallas kernels (``use_pallas=True``) run through
+    the Pallas interpreter — never on a TPU device.
     """
 
     graph: Graph
@@ -488,6 +486,9 @@ class CompiledExecutor:
     steps: int
     offsets: Dict[str, Tuple[int, int]]    # tensor -> (byte offset, bytes)
     zero_copy_reads: int = 0    # ring windows fused into their consumers
+    device: Any = None           # jax.Device the arenas are placed on
+    use_pallas: bool = False
+    interpret: bool = False      # Pallas interpreter (never on a TPU)
     # guard-byte debug mode: (offset, size) arena ranges no placement ever
     # covers; () in production (guard_bytes=0 plans) — the arena is then
     # byte-identical to the un-guarded executor
@@ -513,17 +514,18 @@ class CompiledExecutor:
         key = ("batched", donate)
         if key not in self._fn_cache:
             f = jax.vmap(self.raw_fn)
-            self._fn_cache[key] = (jax.jit(f, donate_argnums=0) if donate
-                                   else jax.jit(f))
+            self._fn_cache[key] = _placed(
+                jax.jit(f, donate_argnums=0) if donate else jax.jit(f),
+                self.device)
         return self._fn_cache[key]
 
     def replicated_fn(self, replicas: int) -> Callable:
         """``[R, B, arena] -> [R, B, arena]``: the vmapped arena program
-        pmapped over the first ``replicas`` visible devices — each replica
-        executes its lane batch independently (no collectives; requests
-        are embarrassingly parallel), so per-replica results are
-        bit-identical to the single-device ``batched_fn``."""
-        devices = jax.devices()
+        pmapped over the first ``replicas`` devices of this executor's
+        platform — each replica executes its lane batch independently (no
+        collectives; requests are embarrassingly parallel), so per-replica
+        results are bit-identical to the single-device ``batched_fn``."""
+        devices = jax.devices(self.device.platform)
         if replicas > len(devices):
             raise ValueError(
                 f"replicas={replicas} but only {len(devices)} devices "
@@ -536,24 +538,24 @@ class CompiledExecutor:
                                            devices=devices[:replicas])
         return self._fn_cache[key]
 
-    def pad_arena(self):
+    def pad_arena(self) -> np.ndarray:
         """An all-zeros arena for pad lanes (ragged tails): executed but
         never read back, and visibly not a duplicated request."""
-        return jnp.zeros((self.arena_size,), self.dtype)
+        return np.zeros((self.arena_size,), np.uint8)
 
-    def make_arena(self, inputs: Dict[str, Any]):
-        """Fresh arena with the graph inputs written (as bytes) at their
-        offsets.  Input values must already be in the tensor's declared
+    def make_arena(self, inputs: Dict[str, Any]) -> np.ndarray:
+        """Fresh host arena with the graph inputs written (as bytes) at
+        their offsets; ``fn``/``batched_fn`` move it to ``device`` in one
+        transfer.  Input values must already be in the tensor's declared
         dtype — an int8 graph takes quantized int8 inputs."""
         g = self.graph
         needed = {c for c in g.constants() if g.consumers(c)}
         missing = needed - set(inputs)
         if missing:
             raise ValueError(f"missing graph inputs: {sorted(missing)}")
-        arena = jnp.zeros((self.arena_size,), self.dtype)
+        arena = np.zeros((self.arena_size,), np.uint8)
         for off, size in self.guard_regions:   # () in production plans
-            arena = lax.dynamic_update_slice(
-                arena, jnp.full((size,), CANARY_BYTE, self.dtype), (off,))
+            arena[off:off + size] = CANARY_BYTE
         for name, value in inputs.items():
             if name not in g.tensors:
                 raise ValueError(f"unknown tensor {name!r}")
@@ -564,17 +566,21 @@ class CompiledExecutor:
             off, size = self._offsets(name)
             t = g.tensors[name]
             want = jnp.dtype(_JNP_DTYPES[t.dtype])
-            val = jnp.asarray(value)
-            if val.dtype != want:     # same contract as MicroInterpreter
+            val = np.asarray(value)
+            # the dtype jnp.asarray would give (float64 -> float32 unless
+            # x64 is on): same contract as MicroInterpreter
+            have = jax.dtypes.canonicalize_dtype(val.dtype)
+            if have != want:
                 raise ValueError(
-                    f"input {name!r} is {val.dtype}, graph declares "
+                    f"input {name!r} is {have}, graph declares "
                     f"{t.dtype} (quantize inputs for int8 graphs)")
-            flat = jnp.ravel(val)
+            flat = np.ascontiguousarray(val.astype(have, copy=False)
+                                        ).reshape(-1)
             if flat.shape[0] != t.elements:
                 raise ValueError(
                     f"input {name!r}: got {flat.shape[0]} elements, "
                     f"plan expects {t.elements} ({size} bytes as {t.dtype})")
-            arena = lax.dynamic_update_slice(arena, _as_bytes(flat), (off,))
+            arena[off:off + size] = flat.view(np.uint8)
         return arena
 
     def outputs_from(self, arena, as_numpy: bool = True) -> Dict[str, Any]:
@@ -615,11 +621,29 @@ class CompiledExecutor:
         return self.outputs_from(arena, as_numpy)
 
 
+def resolve_interpret(device, interpret: Optional[bool] = None) -> bool:
+    """Whether Pallas kernels of a program for ``device`` run through the
+    Pallas interpreter.  ``None`` decides from the device: Mosaic on a
+    TPU, the interpreter anywhere else.  Interpret mode on a TPU device is
+    refused — a chip program never falls back to the interpreter."""
+    on_tpu = device.platform == "tpu"
+    if interpret is None:
+        return not on_tpu
+    if interpret and on_tpu:
+        raise ValueError(
+            f"interpret-mode Pallas kernels requested for a program on "
+            f"{device} ({device.device_kind}); a TPU program compiles its "
+            f"kernels with Mosaic — place interpret-mode programs on the "
+            f"CPU (device=jax.devices('cpu')[0])")
+    return bool(interpret)
+
+
 def compile_schedule(graph: Graph,
                      schedule: Optional[Sequence[Operator]] = None,
                      plan: Optional[ArenaPlan] = None, *,
                      use_pallas: bool = False,
                      interpret: Optional[bool] = None,
+                     device=None,
                      roll_loops: bool = True,
                      zero_copy_rings: bool = True,
                      fuse: bool = False,
@@ -640,7 +664,11 @@ def compile_schedule(graph: Graph,
     ``pex_ring_read``'s window gather into its consumer instead of
     materialising the window in the arena — bit-safe by construction (only
     integer-exact consumers qualify; see ``_zero_copy_reads``) and a pure
-    win: one fewer copy and barrier per streamed slice."""
+    win: one fewer copy and barrier per streamed slice.
+
+    ``device`` (default: the first of ``jax.devices()``) is where the
+    program runs; ``interpret`` is resolved once against it
+    (``resolve_interpret``) and recorded on the executor."""
     sched = list(schedule) if schedule is not None else graph.default_schedule()
     if not graph.is_valid_schedule(sched):
         raise ValueError("invalid schedule for this graph")
@@ -658,6 +686,9 @@ def compile_schedule(graph: Graph,
                     f"misaligned byte offset {offsets[t][0]}; plan with "
                     f"ArenaPlanner.plan(..., alignment=None) so offsets "
                     f"are aligned to the widest itemsize")
+    if device is None:
+        device = jax.devices()[0]
+    interpret = resolve_interpret(device, interpret)
     ctx = LoweringCtx(graph, use_pallas=use_pallas, interpret=interpret)
     zc = (frozenset(_zero_copy_reads(graph, sched)) if zero_copy_rings
           else frozenset())
@@ -769,7 +800,8 @@ def compile_schedule(graph: Graph,
                 arena = step(arena, item, pending)
         return arena
 
-    fn = jax.jit(raw_fn, donate_argnums=0) if donate else jax.jit(raw_fn)
+    fn = _placed(jax.jit(raw_fn, donate_argnums=0) if donate
+                 else jax.jit(raw_fn), device)
     loops = [it for it in items if isinstance(it, _RolledLoop)]
     return CompiledExecutor(
         graph=graph, schedule=sched, plan=plan,
@@ -778,5 +810,6 @@ def compile_schedule(graph: Graph,
         rolled_loops=len(loops),
         rolled_ops=sum(lp.n * len(lp.templates) for lp in loops),
         steps=len(sched), offsets=offsets, zero_copy_reads=len(zc),
+        device=device, use_pallas=use_pallas, interpret=interpret,
         guard_regions=tuple(plan.guard_regions())
         if getattr(plan, "guard_bytes", 0) else ())

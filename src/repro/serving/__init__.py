@@ -3,7 +3,7 @@
 Submodules are imported lazily (PEP 562) so that ``force_host_devices``
 can be imported and called **before anything initialises jax** — the CPU
 replica mesh only exists if ``--xla_force_host_platform_device_count=N``
-is in ``XLA_FLAGS`` at first jax init (SNIPPETS.md Snippets 2–3)::
+is in ``XLA_FLAGS`` at first jax init (SNIPPETS.md Snippet 2)::
 
     from repro.serving import force_host_devices
     force_host_devices(4)           # must precede the first jax import
@@ -39,6 +39,15 @@ def force_host_devices(n: int) -> None:
                 f"before the first jax import")
 
 
+def cpu_platform_requested() -> bool:
+    """Whether ``JAX_PLATFORMS`` puts JAX on the CPU, read from the
+    environment without initialising JAX.  Only there are replica meshes
+    made of virtual host devices, which need ``force_host_devices`` in a
+    fresh process; on a TPU the chips belong to the first process that
+    initialises JAX, so work that needs them runs in that process."""
+    return os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip() == "cpu"
+
+
 _EXPORTS = {
     "GraphServingEngine": ".engine",
     "Request": ".engine",
@@ -57,7 +66,7 @@ _EXPORTS = {
     "dispatch_with_retry": ".faults",
 }
 
-__all__ = ["force_host_devices"] + sorted(_EXPORTS)
+__all__ = ["cpu_platform_requested", "force_host_devices"] + sorted(_EXPORTS)
 
 
 def __getattr__(name: str):

@@ -142,7 +142,7 @@ class GraphServingEngine:
             # the jitted batch fn donates its input, so each retry attempt
             # must re-stack from the (undonated) per-lane arenas
             arenas, r, w = dispatch_with_retry(
-                lambda s=stack: self._batched(jnp.stack(s)),
+                lambda s=stack: self._batched(np.stack(s)),
                 faults=self.faults, max_retries=self.max_retries,
                 dispatch_timeout=self.dispatch_timeout)
             retried += r

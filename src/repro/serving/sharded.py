@@ -55,7 +55,6 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.errors import (DeviceInitError, DispatchFailedError,
@@ -105,8 +104,9 @@ class ShardedServingEngine:
         self._faults = (FaultInjector(faults)
                         if isinstance(faults, FaultPlan) else faults)
         self._degraded: List[str] = list(deployment.degraded)
-        n_dev = len(jax.devices())
-        self.replicas = n_dev if replicas is None else min(replicas, n_dev)
+        devices = jax.devices(self.executor.device.platform)
+        self.replicas = (len(devices) if replicas is None
+                         else min(replicas, len(devices)))
         if self.replicas < 1:
             raise ValueError("need at least one replica")
         self.lanes = int(lanes)
@@ -124,11 +124,15 @@ class ShardedServingEngine:
                 f"replica mesh init failed ({type(e).__name__}: {e}); "
                 f"falling back to single-device serving")
             self.replicas = 1
+            devices = [self.executor.device]
             size = self.executor.arena_size
             batched = self.executor.batched_fn()
             self._fn = (lambda batch:
                         batched(batch.reshape(self.lanes, size))
                         .reshape(1, self.lanes, size))
+        # the devices the replicas run on, in replica order
+        self.devices = list(devices[:self.replicas])
+        self._per_replica = [0] * self.replicas
         self._queue = AdmissionQueue(max_pending=max_pending)
         self._results: Dict[int, Any] = {}
         self._latencies: List[float] = []
@@ -243,7 +247,7 @@ class ShardedServingEngine:
         # batched_fn does — re-stacking per attempt keeps retry safe in
         # both (the per-lane arenas in ``stack`` are never donated)
         def dispatch():
-            batch = jnp.stack(stack).reshape(
+            batch = np.stack(stack).reshape(
                 (self.replicas, self.lanes, ex.arena_size))
             arenas = self._fn(batch)
             jax.block_until_ready(arenas)
@@ -277,6 +281,7 @@ class ShardedServingEngine:
                 r_, b_ = divmod(i, self.lanes)   # are pads: never extracted
                 self._results[req.rid] = ex.outputs_from(arenas[r_, b_])
                 self._latencies.append(t_done - req.t_submit)
+                self._per_replica[r_] += 1
             self._completed += len(admitted)
             return len(admitted)
 
@@ -305,6 +310,7 @@ class ShardedServingEngine:
                 continue
             self._results[req.rid] = ex.outputs_from(lane)
             self._latencies.append(t_done - req.t_submit)
+            self._per_replica[r_] += 1
             done += 1
         self._completed += done
         return done
@@ -335,6 +341,8 @@ class ShardedServingEngine:
         self.stats.failed = self._failed
         self.stats.watchdog_trips = self._trips
         self.stats.degraded = list(self._degraded) or None
+        self.stats.replica_requests = list(self._per_replica)
+        self._per_replica = [0] * self.replicas
         self._completed = 0
         self._admitted = 0
         self._retried = 0

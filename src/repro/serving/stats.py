@@ -66,6 +66,9 @@ class EngineStats:
     requests_per_s: float = 0.0          # true requests / wall
     p50_ms: float = 0.0                  # per-request latency percentiles
     p99_ms: float = 0.0                  # (admission -> completion)
+    # requests completed per replica device (sharded engine), in device
+    # order; None on single-device engines
+    replica_requests: Optional[List[int]] = None
 
     # ---- robustness (failure layer, DESIGN.md §12).  These are NOT in
     # the as_json falsy-drop list on purpose: a zero here is a *measured*

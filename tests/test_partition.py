@@ -182,6 +182,16 @@ def test_budget_mode_only_partitions_when_needed():
 
 
 # ------------------------------------------------------------------ jaxpr pex
+def _int_valued_mlp_operands(rng):
+    """Small-integer-valued f32 MLP operands: every sum is exact in f32,
+    so sliced dot_generals must match the whole one bit-for-bit whatever
+    GEMM blocking XLA CPU picks for each row count."""
+    def ints(shape):
+        return rng.integers(-3, 4, size=shape).astype(np.float32)
+    import jax.numpy as jnp
+    return ints((32, 512)), ints((512, 32)), jnp.asarray(ints((256, 32)))
+
+
 def test_jaxpr_partial_execution_mlp():
     jax = pytest.importorskip("jax")
     import jax.numpy as jnp
@@ -190,22 +200,18 @@ def test_jaxpr_partial_execution_mlp():
     from repro.core.jaxpr_reorder import peak_liveness
 
     rng = np.random.default_rng(0)
-    w1 = rng.standard_normal((32, 512)).astype(np.float32)
-    w2 = rng.standard_normal((512, 32)).astype(np.float32)
+    w1, w2, x = _int_valued_mlp_operands(rng)
 
     def mlp(x):
-        return jnp.tanh(x @ w1) @ w2       # fat (256, 512) interior
+        return jnp.maximum(x @ w1, 0.0) @ w2      # fat (256, 512) interior
 
-    x = jnp.asarray(rng.standard_normal((256, 32)).astype(np.float32))
     closed = jax.make_jaxpr(mlp)(x)
     pc, n_runs = partial_execute_closed_jaxpr(closed)
     assert n_runs == 1
     assert peak_liveness(pc) < peak_liveness(closed)
     ref = np.asarray(eval_jaxpr(closed.jaxpr, closed.consts, x)[0])
     got = np.asarray(eval_jaxpr(pc.jaxpr, pc.consts, x)[0])
-    # sliced dot_general: float-tolerance equivalence (GEMM kernel selection
-    # depends on the row count; see jaxpr_partial docstring)
-    np.testing.assert_allclose(got, ref, rtol=2e-6, atol=1e-6)
+    np.testing.assert_array_equal(got, ref)
 
 
 def test_jaxpr_elementwise_slicing_bit_identical():
@@ -235,13 +241,11 @@ def test_jaxpr_reorder_with_partition_budget():
     from repro.core.jaxpr_reorder import reorder_closed_jaxpr
 
     rng = np.random.default_rng(2)
-    w1 = rng.standard_normal((32, 512)).astype(np.float32)
-    w2 = rng.standard_normal((512, 32)).astype(np.float32)
+    w1, w2, x = _int_valued_mlp_operands(rng)
 
     def mlp(x):
-        return jnp.tanh(x @ w1) @ w2
+        return jnp.maximum(x @ w1, 0.0) @ w2
 
-    x = jnp.asarray(rng.standard_normal((256, 32)).astype(np.float32))
     closed = jax.make_jaxpr(mlp)(x)
     _, base = reorder_closed_jaxpr(closed)
     budget = base.peak_after // 2
@@ -249,7 +253,7 @@ def test_jaxpr_reorder_with_partition_budget():
     assert rep.method.endswith("+pex") and rep.peak_after < base.peak_after
     ref = np.asarray(eval_jaxpr(closed.jaxpr, closed.consts, x)[0])
     got = np.asarray(eval_jaxpr(nc.jaxpr, nc.consts, x)[0])
-    np.testing.assert_allclose(got, ref, rtol=2e-6, atol=1e-6)
+    np.testing.assert_array_equal(got, ref)
     # without a budget the behaviour is unchanged
     _, plain = reorder_closed_jaxpr(closed)
     assert plain.peak_after == base.peak_after

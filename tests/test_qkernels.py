@@ -7,6 +7,8 @@ differs), every assertion here is ``assert_array_equal``: int32 accumulation
 of int8 products is exact and order-independent, and the kernels replay the
 reference requantize sequence literally — so the fused path must cost zero
 ULPs, on every shape, stride and padding the MCU graphs produce."""
+from types import SimpleNamespace
+
 import numpy as np
 import pytest
 
@@ -21,6 +23,7 @@ from repro.graphs import quantize_graph, random_input
 from repro.graphs.cnn_ops import CNNBuilder, qadd, qconv2d, qdwconv2d
 from repro.kernels import qconv_add_fused, qconv_fused, qdwconv_fused
 from repro.mcu import MicroInterpreter, compile_schedule
+from repro.mcu.compile import resolve_interpret
 
 
 def qrand(rng, shape):
@@ -215,3 +218,19 @@ def test_zero_copy_ring_reads_bit_identical():
         for o in gp.outputs:
             np.testing.assert_array_equal(ref.outputs[o], out[o])
             np.testing.assert_array_equal(copying.run(x)[o], out[o])
+
+
+def test_interpret_mode_is_resolved_once_from_the_device():
+    """Mosaic for a TPU program, the interpreter elsewhere; asking for
+    interpret-mode kernels on a TPU is an error, never a silent fallback.
+    The executor records the decision."""
+    cpu = jax.devices("cpu")[0]
+    tpu = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert resolve_interpret(tpu) is False
+    assert resolve_interpret(tpu, False) is False
+    assert resolve_interpret(cpu) is True
+    assert resolve_interpret(cpu, False) is False
+    with pytest.raises(ValueError, match="interpret-mode"):
+        resolve_interpret(tpu, True)
+    ex = compile_schedule(_chain_cnn(), use_pallas=True, device=cpu)
+    assert ex.interpret is True and ex.device == cpu

@@ -228,6 +228,9 @@ for ref, o in zip(refs, outs):
         np.testing.assert_array_equal(ref[t], o[t])
 st = eng.stats
 assert st.dispatches == 2 and st.padded_lanes == 4 and st.requests == 8
+# requests land on every replica; the ragged 2nd step fills replica 0
+assert st.replica_requests == [4, 2, 2], st.replica_requests
+assert eng.devices == jax.devices()[:3]
 print("MULTI_OK")
 """
 
@@ -246,3 +249,15 @@ def test_sharded_multi_replica_bit_identical_subprocess():
                           timeout=300)
     assert proc.returncode == 0, f"\n{proc.stdout}\n{proc.stderr}"
     assert "MULTI_OK" in proc.stdout
+
+
+def test_cpu_platform_requested_reads_only_the_environment(monkeypatch):
+    """Benchmarks start a forced-host-device child only on the CPU; on a
+    chip the parent holds the devices, so the choice must not need JAX."""
+    from repro.serving import cpu_platform_requested
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert cpu_platform_requested()
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    assert not cpu_platform_requested()
+    monkeypatch.delenv("JAX_PLATFORMS")
+    assert not cpu_platform_requested()
