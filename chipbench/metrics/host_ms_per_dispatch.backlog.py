@@ -1,0 +1,6 @@
+"""serving host work: step wall time less device busy time, backlog cells."""
+from lib import readers
+
+
+def read(run):
+    return readers.host_ms_per_dispatch(run, "backlog")

@@ -1,0 +1,6 @@
+"""kernels: the pointwise kernel's least time over its device time, in %."""
+from lib import readers
+
+
+def read(run):
+    return readers.roofline_pct(run, "qconv1x1")

@@ -1,0 +1,6 @@
+"""kernels: the depthwise kernel's least time over its device time, in %."""
+from lib import readers
+
+
+def read(run):
+    return readers.roofline_pct(run, "qdwconv")
