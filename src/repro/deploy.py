@@ -34,9 +34,12 @@ the honest int8 dtype contract inside.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.core import ArenaPlanner, schedule as _schedule
@@ -232,6 +235,17 @@ class Deployment:
         return self.qmodel.dequantize_outputs(outputs)
 
 
+@contextlib.contextmanager
+def _phase(name: str):
+    """Report the wall time of one phase of ``build`` as the
+    ``jax.monitoring`` duration event ``/repro/deploy/<name>``; a phase
+    that raises reports nothing."""
+    t = time.perf_counter()
+    yield
+    jax.monitoring.record_event_duration_secs(
+        f"/repro/deploy/{name}", time.perf_counter() - t)
+
+
 def build(graph: Graph, *, arena_budget: Optional[int] = None,
           quantize: bool = False, calibration=None,
           use_pallas: bool = False, objective: str = "memory",
@@ -264,26 +278,36 @@ def build(graph: Graph, *, arena_budget: Optional[int] = None,
       run time (``GuardViolation`` on a stomp).  0 (default) is
       byte-identical to the historical planner/executor.
     * extra keyword arguments are forwarded to ``core.schedule()``.
+
+    Each phase of an attempt reports its wall time as a ``jax.monitoring``
+    duration event: ``/repro/deploy/quantize`` (``quantize=True`` only),
+    ``/repro/deploy/schedule``, ``/repro/deploy/plan`` (plan and
+    validate) and ``/repro/deploy/lower``.
     """
     qmodel = None
     if quantize:
         from repro.graphs import quantize_graph
-        qmodel = quantize_graph(graph, calibration)
+        with _phase("quantize"):
+            qmodel = quantize_graph(graph, calibration)
         graph = qmodel.graph
 
     # one attempt = the full schedule → plan → validate → compile chain for
     # one rung set; any failure inside is that rung set's failure
     def attempt(rungs):
-        res = _schedule(graph, arena_budget=arena_budget,
-                        partition=partition, objective=objective,
-                        macs_cap=macs_cap,
-                        **(schedule_opts if rungs is None
-                           else {**schedule_opts, "rungs": rungs}))
+        with _phase("schedule"):
+            res = _schedule(graph, arena_budget=arena_budget,
+                            partition=partition, objective=objective,
+                            macs_cap=macs_cap,
+                            **(schedule_opts if rungs is None
+                               else {**schedule_opts, "rungs": rungs}))
         eg = res.graph if res.graph is not None else graph
-        plan = ArenaPlanner.plan(eg, res.schedule, guard_bytes=guard_bytes)
-        ArenaPlanner.validate(plan, eg)
-        ex = compile_schedule(eg, res.schedule, plan,
-                              use_pallas=use_pallas, fuse=fuse)
+        with _phase("plan"):
+            plan = ArenaPlanner.plan(eg, res.schedule,
+                                     guard_bytes=guard_bytes)
+            ArenaPlanner.validate(plan, eg)
+        with _phase("lower"):
+            ex = compile_schedule(eg, res.schedule, plan,
+                                  use_pallas=use_pallas, fuse=fuse)
         return res, eg, plan, ex
 
     ladder = (_FALLBACK_RUNGS if "rungs" not in schedule_opts

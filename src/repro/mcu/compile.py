@@ -33,7 +33,12 @@ MCUNet pair their planners with a compiled runtime:
   **zero-copy**: the modular gather fuses into the consumer's computation
   as an SSA value and the window is never re-materialised in the arena
   (``zero_copy_rings=True``, see ``_zero_copy_reads`` for the eligibility
-  proof obligations) — in both the straight-line and rolled-loop paths.
+  proof obligations) — in both the straight-line and rolled-loop paths;
+* each operator is lowered under ``jax.named_scope(op.name)`` and each
+  rolled loop under ``jax.named_scope(f"loop{k}")`` (k counts the loops in
+  schedule order), so a device op in a profile carries the operator or
+  loop it came from in its ``op_name`` metadata.  Metadata only: the
+  computation is the same.
 
 Lowering rules are registered per operator ``kind`` next to the semantics
 (``graphs/cnn_ops.py`` registers conv/dwconv/maxpool/add, optionally routing
@@ -718,15 +723,16 @@ def compile_schedule(graph: Graph,
         return arena if fuse else lax.optimization_barrier(arena)
 
     def step(arena, op: Operator, pending: Dict[str, Any]):
-        args = [pending.pop(i) if i in pending else read(arena, i)
-                for i in op.inputs]
-        val = lower_op(ctx, op, *args)
-        if op.output in zc:       # zero-copy: flows straight to the consumer
-            pending[op.output] = val
-            return arena
-        return barrier(write(arena, op.output, val))
+        with jax.named_scope(op.name):
+            args = [pending.pop(i) if i in pending else read(arena, i)
+                    for i in op.inputs]
+            val = lower_op(ctx, op, *args)
+            if op.output in zc:   # zero-copy: flows straight to the consumer
+                pending[op.output] = val
+                return arena
+            return barrier(write(arena, op.output, val))
 
-    def loop_step(arena, loop: _RolledLoop):
+    def loop_step(arena, loop: _RolledLoop, k: int):
         def body(i, arena):
             deferred: Dict[int, Any] = {}
             for t_i, tpl in enumerate(loop.templates):
@@ -789,13 +795,16 @@ def compile_schedule(graph: Graph,
                         arena, flat, (tpl.out_slot.offset[i],))
                 arena = barrier(arena)
             return arena
-        return lax.fori_loop(0, loop.n, body, arena)
+        with jax.named_scope(f"loop{k}"):
+            return lax.fori_loop(0, loop.n, body, arena)
 
     def raw_fn(arena):
         pending: Dict[str, Any] = {}
+        loops = 0
         for item in items:
             if isinstance(item, _RolledLoop):
-                arena = loop_step(arena, item)
+                arena = loop_step(arena, item, loops)
+                loops += 1
             else:
                 arena = step(arena, item, pending)
         return arena

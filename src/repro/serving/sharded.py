@@ -66,6 +66,12 @@ from repro.serving.faults import (FaultInjector, FaultPlan,
 from repro.serving.stats import EngineStats
 
 
+def _span(phase: str):
+    """A host span ``repro.serving.<phase>`` in the profiler's trace, on
+    the device trace's clock; a few microseconds when no profiler runs."""
+    return jax.profiler.TraceAnnotation(f"repro.serving.{phase}")
+
+
 class ShardedServingEngine:
     """Continuous-batching engine over an ``[R, L]`` replica × lane grid.
 
@@ -223,34 +229,43 @@ class ShardedServingEngine:
         (priority, arrival) — expiring past-deadline ones — pad the ragged
         remainder with zero arenas, execute the replicated program under
         retry/watchdog, detect injected poison, complete the survivors.
-        Returns how many completed successfully."""
+        Returns how many completed successfully.
+
+        Each phase is a host span in the profiler's trace:
+        ``repro.serving.admit`` (pop and expire), ``.stage`` (arenas and
+        pads), ``.launch`` (stack, transfer, enqueue) and ``.wait``
+        (device), once per attempt, and ``.extract`` (outputs)."""
         if not self._queue:
             return 0
         ex = self.executor
-        now = self._clock()
-        admitted, expired = self._queue.pop_ready(self.capacity, now)
-        for req in expired:
-            self._results[req.rid] = RequestError(
-                req.rid, "expired",
-                f"deadline {req.deadline:.6f} passed at {now:.6f}")
+        with _span("admit"):
+            now = self._clock()
+            admitted, expired = self._queue.pop_ready(self.capacity, now)
+            for req in expired:
+                self._results[req.rid] = RequestError(
+                    req.rid, "expired",
+                    f"deadline {req.deadline:.6f} passed at {now:.6f}")
         if not admitted:
             return 0
         self._admitted += len(admitted)
-        stack = [ex.make_arena(req.inputs) for req in admitted]
-        n_pad = self.capacity - len(stack)
-        if n_pad:
-            pad = ex.pad_arena()
-            stack.extend([pad] * n_pad)
-            self._padded += n_pad
+        with _span("stage"):
+            stack = [ex.make_arena(req.inputs) for req in admitted]
+            n_pad = self.capacity - len(stack)
+            if n_pad:
+                pad = ex.pad_arena()
+                stack.extend([pad] * n_pad)
+                self._padded += n_pad
 
         # the pmap path does not donate, but the single-device fallback's
         # batched_fn does — re-stacking per attempt keeps retry safe in
         # both (the per-lane arenas in ``stack`` are never donated)
         def dispatch():
-            batch = np.stack(stack).reshape(
-                (self.replicas, self.lanes, ex.arena_size))
-            arenas = self._fn(batch)
-            jax.block_until_ready(arenas)
+            with _span("launch"):
+                batch = np.stack(stack).reshape(
+                    (self.replicas, self.lanes, ex.arena_size))
+                arenas = self._fn(batch)
+            with _span("wait"):
+                jax.block_until_ready(arenas)
             return arenas
 
         try:
@@ -270,7 +285,14 @@ class ShardedServingEngine:
         self._trips += w
         self._dispatches += 1
         t_done = self._clock()
+        with _span("extract"):
+            return self._extract(admitted, arenas, t_done)
 
+    def _extract(self, admitted: List[QueuedRequest], arenas,
+                 t_done: float) -> int:
+        """Complete the admitted requests from a dispatch's ``[R, L]``
+        arenas; returns how many completed successfully."""
+        ex = self.executor
         lane_faults = (self._faults is not None
                        and self._faults.plan.any_lane_faults())
         if not lane_faults and not ex.guard_regions:
