@@ -530,6 +530,13 @@ class CompiledExecutor:
         platform — each replica executes its lane batch independently (no
         collectives; requests are embarrassingly parallel), so per-replica
         results are bit-identical to the single-device ``batched_fn``."""
+        key = ("replicated", replicas)
+        if key not in self._fn_cache:
+            self._fn_cache[key] = jax.pmap(
+                jax.vmap(self.raw_fn), devices=self._replica_devices(replicas))
+        return self._fn_cache[key]
+
+    def _replica_devices(self, replicas: int) -> list:
         devices = jax.devices(self.device.platform)
         if replicas > len(devices):
             raise ValueError(
@@ -537,11 +544,59 @@ class CompiledExecutor:
                 f"visible; set XLA_FLAGS="
                 f"--xla_force_host_platform_device_count={replicas} "
                 f"before the first jax import (serving.force_host_devices)")
-        key = ("replicated", replicas)
+        return devices[:replicas]
+
+    def _output_specs(self):
+        """(name, arena offset, bytes, dtype, shape) of every graph output,
+        in ``graph.outputs`` order."""
+        for o in self.graph.outputs:
+            off, size = self._offsets(o)
+            t = self.graph.tensors[o]
+            yield o, off, size, t.dtype, (tuple(t.shape) if t.shape
+                                          else (t.elements,))
+
+    def output_block_fn(self, replicas: Optional[int] = None) -> Callable:
+        """``[R, L, arena] -> [R, L, out_bytes]``: the byte ranges of every
+        graph output, concatenated in ``graph.outputs`` order, of every
+        lane, in one device op, so one transfer reads a whole dispatch's
+        outputs (``outputs_from_block`` cuts them per lane).  With
+        ``replicas`` it is pmapped over the devices of ``replicated_fn
+        (replicas)`` and reads that program's output where it lies; with
+        None it is jitted, for ``batched_fn``'s output shaped ``[1, L,
+        arena]``."""
+        key = ("outputs", replicas)
         if key not in self._fn_cache:
-            self._fn_cache[key] = jax.pmap(jax.vmap(self.raw_fn),
-                                           devices=devices[:replicas])
+            ranges = [(off, size) for _, off, size, _, _
+                      in self._output_specs()]
+
+            def pick(arena):
+                return jnp.concatenate([arena[off:off + size]
+                                        for off, size in ranges])
+            lanes = jax.vmap(pick)
+            self._fn_cache[key] = (
+                jax.jit(jax.vmap(lanes)) if replicas is None else
+                jax.pmap(lanes, devices=self._replica_devices(replicas)))
         return self._fn_cache[key]
+
+    def outputs_from_block(self, block, lanes: int
+                           ) -> List[Dict[str, np.ndarray]]:
+        """The output dicts of the first ``lanes`` lanes, in ``[R, L]``
+        row-major order, of an ``output_block_fn`` block: one transfer
+        (``np.asarray``) of the whole block, then per output a dtype view
+        of its byte range.  Each value is a read-only view of that host
+        copy, bit-identical to ``outputs_from`` of the lane's arena (the
+        arena's bytes are little-endian, as ``lax.bitcast_convert_type``
+        reads them)."""
+        host = np.asarray(block)
+        host = host.reshape(-1, host.shape[-1])[:lanes]
+        host.flags.writeable = False
+        views, start = [], 0
+        for name, _, size, dtype, shape in self._output_specs():
+            col = host[:, start:start + size].view(
+                jnp.dtype(_JNP_DTYPES[dtype]))
+            views.append((name, col.reshape((lanes,) + shape)))
+            start += size
+        return [{name: v[i] for name, v in views} for i in range(lanes)]
 
     def pad_arena(self) -> np.ndarray:
         """An all-zeros arena for pad lanes (ragged tails): executed but
@@ -590,11 +645,8 @@ class CompiledExecutor:
 
     def outputs_from(self, arena, as_numpy: bool = True) -> Dict[str, Any]:
         out: Dict[str, Any] = {}
-        for o in self.graph.outputs:
-            off, size = self._offsets(o)
-            t = self.graph.tensors[o]
-            shape = tuple(t.shape) if t.shape else (t.elements,)
-            val = _view_bytes(arena[off:off + size], t.dtype, shape)
+        for o, off, size, dtype, shape in self._output_specs():
+            val = _view_bytes(arena[off:off + size], dtype, shape)
             out[o] = np.asarray(val) if as_numpy else val
         return out
 

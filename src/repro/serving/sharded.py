@@ -39,15 +39,20 @@ boundary:
   note in ``stats.degraded`` instead of refusing to serve.
 * **Honest ragged tails** — pad lanes are explicit all-zero arenas:
   executed (one compiled shape), counted in ``stats.padded_lanes``, never
-  extracted, never in per-request latency.
+  returned, never in per-request latency.
+* **One transfer a dispatch** — with no lane faults and no guards, every
+  lane's outputs leave the device in one op and one transfer
+  (``CompiledExecutor.output_block_fn``) and are cut per lane on the host
+  as read-only views; ``stats.batched_extracts`` counts those dispatches.
 * **Typed stats** — latency p50/p99 and throughput plus the failure-layer
-  counters (admitted/expired/shed/retried/failed/watchdog_trips) in
+  counters (admitted/expired/shed/retried/failed/watchdog_trips/
+  batched_extracts) in
   ``EngineStats``; ``benchmarks/bench_serving.py`` gates requests/s as a
   floor and expired/shed as exact zeros in the no-fault configuration.
 
 With no faults, no guards, and default admission (no deadlines, no bound)
-the dispatch path is unchanged from PR 8: same jax calls, same extraction,
-bit-identical outputs under any arrival interleaving.
+outputs are bit-identical to ``Deployment.run`` under any arrival
+interleaving.
 """
 from __future__ import annotations
 
@@ -120,6 +125,7 @@ class ShardedServingEngine:
             if self._faults is not None:
                 self._faults.engine_init()
             self._fn = self.executor.replicated_fn(self.replicas)
+            self._out_fn = self.executor.output_block_fn(self.replicas)
         except (DeviceInitError, RuntimeError) as e:
             if not fallback_single_device:
                 raise
@@ -136,6 +142,7 @@ class ShardedServingEngine:
             self._fn = (lambda batch:
                         batched(batch.reshape(self.lanes, size))
                         .reshape(1, self.lanes, size))
+            self._out_fn = self.executor.output_block_fn()
         # the devices the replicas run on, in replica order
         self.devices = list(devices[:self.replicas])
         self._per_replica = [0] * self.replicas
@@ -150,6 +157,7 @@ class ShardedServingEngine:
         self._retried = 0
         self._failed = 0
         self._trips = 0
+        self._batched_extracts = 0
         self._t_first_submit: Optional[float] = None
         self.stats = EngineStats(
             arena_bytes=deployment.arena_bytes,
@@ -296,14 +304,15 @@ class ShardedServingEngine:
         lane_faults = (self._faults is not None
                        and self._faults.plan.any_lane_faults())
         if not lane_faults and not ex.guard_regions:
-            # production path: identical to the pre-failure-layer engine —
-            # outputs extracted straight from the device arenas, no host
-            # copy, bit-identity preserved
-            for i, req in enumerate(admitted):   # lanes i >= len(admitted)
-                r_, b_ = divmod(i, self.lanes)   # are pads: never extracted
-                self._results[req.rid] = ex.outputs_from(arenas[r_, b_])
+            # production path: every lane's outputs in one device op and
+            # one transfer, cut per lane as read-only host views; lanes
+            # i >= len(admitted) are pads: never returned
+            outs = ex.outputs_from_block(self._out_fn(arenas), len(admitted))
+            for i, (req, out) in enumerate(zip(admitted, outs)):
+                self._results[req.rid] = out
                 self._latencies.append(t_done - req.t_submit)
-                self._per_replica[r_] += 1
+                self._per_replica[i // self.lanes] += 1
+            self._batched_extracts += 1
             self._completed += len(admitted)
             return len(admitted)
 
@@ -362,6 +371,7 @@ class ShardedServingEngine:
         self.stats.retried = self._retried
         self.stats.failed = self._failed
         self.stats.watchdog_trips = self._trips
+        self.stats.batched_extracts = self._batched_extracts
         self.stats.degraded = list(self._degraded) or None
         self.stats.replica_requests = list(self._per_replica)
         self._per_replica = [0] * self.replicas
@@ -370,6 +380,7 @@ class ShardedServingEngine:
         self._retried = 0
         self._failed = 0
         self._trips = 0
+        self._batched_extracts = 0
         self._dispatches = 0
         self._padded = 0
         self._latencies = []

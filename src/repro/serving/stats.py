@@ -80,6 +80,10 @@ class EngineStats:
     retried: int = 0                     # dispatch/lane retries consumed
     failed: int = 0                      # typed RequestError results
     watchdog_trips: int = 0              # post-hoc watchdog overruns
+    # dispatches whose outputs were read in one device op and one
+    # transfer; dispatches - batched_extracts took the host-copy path
+    # (lane faults or guard bytes)
+    batched_extracts: int = 0
     degraded: Optional[List[str]] = None  # degradation notes, None = none
 
     # ---- LLM engine (KV-block arena accounting); None on graph engines

@@ -10,10 +10,12 @@ request lands on, its outputs are **bit-identical** to a one-shot
 grid on a forced 3-device host mesh so real multi-replica pmap assignment
 is covered, not just the degenerate 1-device mesh of the test process.
 """
+import functools
 import os
 import subprocess
 import sys
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -21,8 +23,8 @@ import repro.deploy as deploy
 from repro.graphs import figure1_int8_graph, quantize_graph, random_input
 from repro.graphs.cnn_ops import CNNBuilder
 from repro.core.graph import Graph
-from repro.serving import (EngineStats, GraphServingEngine,
-                           ShardedServingEngine, percentile_ms)
+from repro.serving import (EngineStats, FaultPlan, GraphServingEngine,
+                           RequestError, ShardedServingEngine, percentile_ms)
 
 
 def _tiny_cnn() -> Graph:
@@ -43,13 +45,51 @@ def _tiny_cnn_int8() -> Graph:
     return quantize_graph(g, random_input(g)).graph
 
 
-# fixed-seed grid: the int8 golden graph plus a quantized CNN and its
-# float build (three dtype/shape regimes through the same engines)
+def _uint8_mask() -> Graph:
+    """A float32 input to one uint8 output."""
+    g = Graph()
+    g.add_tensor("x", 4 * 24, shape=(4, 6), dtype="float32")
+    g.add_tensor("h", 4 * 24, shape=(4, 6), dtype="float32")
+    g.add_tensor("mask", 24, shape=(4, 6), dtype="uint8")
+    g.add_operator("scale", ["x"], "h", fn=lambda x: x * 0.5 + 0.25)
+    g.add_operator("to_mask", ["h"], "mask",
+                   fn=lambda h: jnp.clip(jnp.abs(h) * 90, 0, 255)
+                   .astype(jnp.uint8))
+    g.set_outputs(["mask"])
+    return g
+
+
+def _two_outputs() -> Graph:
+    """Two outputs: 3 bfloat16 (6 bytes) then 5 float32, so the float32
+    bytes start unaligned in the concatenated output block."""
+    g = Graph()
+    g.add_tensor("x", 4 * 16, shape=(16,), dtype="float32")
+    g.add_tensor("h", 4 * 16, shape=(16,), dtype="float32")
+    g.add_tensor("head", 2 * 3, shape=(3,), dtype="bfloat16")
+    g.add_tensor("tail", 4 * 5, shape=(5,), dtype="float32")
+    g.add_operator("scale", ["x"], "h", fn=lambda x: x * 0.5 + 0.25)
+    g.add_operator("first", ["h"], "head",
+                   fn=lambda h: h[:3].astype(jnp.bfloat16))
+    g.add_operator("last", ["h"], "tail", fn=lambda h: h[6:11] * h[11:])
+    g.set_outputs(["head", "tail"])
+    return g
+
+
+# fixed-seed grid: the int8 golden graph, a quantized CNN and its float
+# build, a uint8 output and a graph with two outputs (int8, uint8,
+# float32 and bfloat16 outputs through the same engines)
 _GRID = {
     "figure1_int8": figure1_int8_graph,
     "tiny_cnn_int8": _tiny_cnn_int8,
     "tiny_cnn_f32": _tiny_cnn,
+    "uint8_mask": _uint8_mask,
+    "two_outputs": _two_outputs,
 }
+
+
+@functools.lru_cache(maxsize=None)
+def _grid_deployment(name: str):
+    return deploy.build(_GRID[name]())
 
 
 def _requests(g, n, seed0=0):
@@ -182,6 +222,96 @@ def test_sharded_outputs_invariant_under_interleaving(name):
     assert st.requests == 7 and st.dispatches >= 3
 
 
+# ------------------------------------------- one-transfer output extraction
+def _assert_outputs_equal(ref, out, names):
+    assert sorted(out) == sorted(names)
+    for t in names:
+        assert out[t].dtype == ref[t].dtype
+        assert out[t].shape == ref[t].shape
+        assert not out[t].flags.writeable
+        np.testing.assert_array_equal(ref[t], out[t])
+
+
+@pytest.mark.parametrize("lanes,n", [(1, 1), (3, 2), (4, 4)],
+                         ids=["L1", "ragged", "full"])
+@pytest.mark.parametrize("name", sorted(_GRID))
+def test_output_block_matches_outputs_from(name, lanes, n):
+    """The one-transfer path's per-lane outputs are bit-identical to
+    ``outputs_from`` of the same lane's arena; only the first ``n`` lanes
+    are returned (the rest are pads)."""
+    d = _grid_deployment(name)
+    ex = d.executor
+    reqs = _requests(d.exec_graph, n, seed0=40)
+    stack = [ex.make_arena(r) for r in reqs]
+    stack += [ex.pad_arena()] * (lanes - n)
+    arenas = ex.replicated_fn(1)(np.stack(stack).reshape(1, lanes, -1))
+    block = ex.output_block_fn(1)(arenas)
+    out_bytes = sum(ex.offsets[o][1] for o in ex.graph.outputs)
+    assert block.shape == (1, lanes, out_bytes)
+    outs = ex.outputs_from_block(block, n)
+    assert len(outs) == n
+    for b, out in enumerate(outs):
+        _assert_outputs_equal(ex.outputs_from(arenas[0, b]), out,
+                              ex.graph.outputs)
+
+
+@pytest.mark.parametrize("name", sorted(_GRID))
+def test_single_device_fallback_reads_outputs_in_one_transfer(name):
+    """A failed replica-mesh init serves through the jitted single-device
+    program; its ``[1, L, arena]`` output takes the one-transfer path."""
+    d = _grid_deployment(name)
+    reqs = _requests(d.exec_graph, 5, seed0=60)
+    eng = ShardedServingEngine(d, lanes=3,
+                               faults=FaultPlan(fail_engine_init=True))
+    assert eng.replicas == 1 and eng.stats.degraded is None
+    outs = eng.serve(reqs)                   # 3 + a ragged 2
+    for r, o in zip(reqs, outs):
+        _assert_outputs_equal(d.run(r), o, d.exec_graph.outputs)
+    s = eng.stats
+    assert s.degraded and "single-device" in s.degraded[-1]
+    assert (s.requests, s.padded_lanes) == (5, 1)
+    assert s.batched_extracts == s.dispatches == 2
+
+
+def test_batched_extracts_counts_every_no_fault_dispatch():
+    d = _grid_deployment("figure1_int8")
+    eng = ShardedServingEngine(d, lanes=2)
+    eng.serve(_requests(d.exec_graph, 5))
+    assert eng.stats.dispatches == 3
+    assert eng.stats.batched_extracts == 3
+    assert eng.stats.as_json()["batched_extracts"] == 3
+    eng.serve(_requests(d.exec_graph, 2))    # drain() resets the count
+    assert eng.stats.batched_extracts == eng.stats.dispatches == 1
+
+
+@pytest.mark.parametrize("path", ["lane_faults", "guard_bytes"])
+def test_host_copy_path_counts_no_batched_extracts(path):
+    """Lane faults or guard bytes keep the writable host-copy path: no
+    dispatch is counted as batched, and every answer it gives is still
+    bit-identical to ``Deployment.run``."""
+    g = _tiny_cnn()
+    if path == "lane_faults":
+        d = _grid_deployment("tiny_cnn_f32")
+        eng = ShardedServingEngine(
+            d, lanes=2, max_retries=4,
+            faults=FaultPlan(seed=3, corrupt_rate=0.3, nan_rate=0.3))
+    else:
+        d = deploy.build(g, guard_bytes=32)
+        assert d.executor.guard_regions
+        eng = ShardedServingEngine(d, lanes=2)
+    reqs = _requests(g, 6, seed0=80)
+    outs = eng.serve(reqs)
+    ok = [(r, o) for r, o in zip(reqs, outs)
+          if not isinstance(o, RequestError)]
+    assert ok
+    for r, o in ok:
+        ref = d.run(r)
+        for t in g.outputs:
+            np.testing.assert_array_equal(ref[t], o[t])
+    assert eng.stats.dispatches >= 3
+    assert eng.stats.batched_extracts == 0
+
+
 def test_sharded_admission_is_fifo_at_boundaries():
     g = figure1_int8_graph()
     eng = ShardedServingEngine(deploy.build(g), lanes=2)
@@ -230,7 +360,24 @@ st = eng.stats
 assert st.dispatches == 2 and st.padded_lanes == 4 and st.requests == 8
 # requests land on every replica; the ragged 2nd step fills replica 0
 assert st.replica_requests == [4, 2, 2], st.replica_requests
+assert st.batched_extracts == 2
 assert eng.devices == jax.devices()[:3]
+
+# the one-transfer read of a [3, 2] dispatch, from the replicas' own
+# devices (no gather first), ragged: 5 requests and one pad lane
+ex = d.executor
+stack = [ex.make_arena(r) for r in reqs[:5]] + [ex.pad_arena()]
+arenas = ex.replicated_fn(3)(np.stack(stack).reshape(3, 2, -1))
+block = ex.output_block_fn(3)(arenas)
+assert block.sharding.device_set == arenas.sharding.device_set
+assert len(block.sharding.device_set) == 3
+outs = ex.outputs_from_block(block, 5)
+assert len(outs) == 5
+for i, o in enumerate(outs):
+    ref = ex.outputs_from(arenas[divmod(i, 2)])
+    for t in g.outputs:
+        assert not o[t].flags.writeable
+        np.testing.assert_array_equal(ref[t], o[t])
 print("MULTI_OK")
 """
 
