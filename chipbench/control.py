@@ -1,5 +1,6 @@
-"""The control of ``correct``: the plain reference computed one precision
-down (int4 weights), put in the program's place, at a cell's own size:
+"""The control of ``correct``: the plain reference of the cell's model
+(``models/<model>.py``) computed one precision down (int4 weights), put in
+the program's place, at a cell's own size:
 
     python chipbench/control.py --workload tile224k.backlog \
         --seeds 2147483701,2147483702,2147483703
@@ -32,13 +33,14 @@ def main(argv=None) -> int:
     from lib import harness, reference as R, traffic
 
     cell = harness.load_cell(ROOT, args.workload)
-    cfg = cell.config
-    qm8 = R.quantize_model(cfg)
-    qm4 = R.quantize_model(cfg, weight_bits=4)
+    cfg, model = cell.config, cell.model
+    qm8 = model.quantize_model(cfg)
+    qm4 = model.quantize_model(cfg, weight_bits=4)
+    fwd8, fwd4 = model.int8_forward(qm8), model.int8_forward(qm4)
     for seed in [int(s) for s in args.seeds.split(",")]:
         images = traffic.pool_images(cfg, int(cell.mix["pool"]), seed)
-        want = R.logits(qm8, qm8.quantize_input(images))
-        ctrl = R.logits(qm4, qm4.quantize_input(images))
+        want = R.logits(fwd8, qm8.quantize_input(images))
+        ctrl = R.logits(fwd4, qm4.quantize_input(images))
         print(json.dumps({"workload": args.workload, "seed": seed,
                           "device": jax.devices()[0].device_kind,
                           "control": R.compare(list(ctrl), want)}),

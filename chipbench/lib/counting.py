@@ -1,59 +1,50 @@
-"""Operations and bytes of the fused int8 kernels, from the deployed
-schedule's operator shapes.
+"""Operations and bytes of the kernels a dispatch calls, from the deployed
+schedule's operator shapes, and the least time they can take.
 
-Every scheduled ``qconv``/``qdwconv`` is one kernel call per dispatch, and
-a call covers all ``L`` lanes of the dispatch.  Its operations are
-2 x multiply-accumulates x L; its least bytes are each lane's input and
-output activations plus the int8 weights once.  The kernel that serves an
-operator follows the lowering's rule: a 1x1, stride-1 ``qconv`` with no
-explicit padding is ``qconv1x1``, any other ``qconv`` is ``qconv``, and a
-``qdwconv`` is ``qdwconv``.
+Every scheduled operator whose kind has a rule file,
+``chipbench/kernels/<op kind>.py``, is one kernel call per dispatch, and a
+call covers all ``L`` lanes of the dispatch.  The rule file says which
+kernel serves the operator, ``kernel_of(attrs)``, and what one call costs,
+``call_cost(attrs, in_shapes, out_shape, lanes) -> (operations, bytes)``,
+where ``in_shapes`` holds the shape of every activation input.  An
+operator whose kind has no rule file calls no counted kernel and is
+skipped.
 """
 from __future__ import annotations
 
-import math
-from typing import Dict, List, Tuple
+from pathlib import Path
+from types import ModuleType
+from typing import Dict, List, Optional, Tuple
+
+from . import loader
+
+KERNELS = Path(__file__).resolve().parents[1] / "kernels"
 
 Call = Tuple[int, int]                   # (operations, bytes) of one call
 
 
-def _unpadded(pads) -> bool:
-    return pads is None or tuple(pads) == (0, 0)
-
-
-def kernel_of(kind: str, attrs: dict) -> str:
-    if kind == "qdwconv":
-        return "qdwconv"
-    if (attrs["k"] == 1 and attrs["stride"] == 1
-            and _unpadded(attrs.get("pex_pads"))
-            and _unpadded(attrs.get("pex_wpads"))):
-        return "qconv1x1"
-    return "qconv"
-
-
-def call_cost(kind: str, attrs: dict, in_shape, out_shape, lanes: int
-              ) -> Call:
-    """(operations, least bytes) of one kernel call over ``lanes``."""
-    oh, ow, cout = out_shape
-    k = attrs["k"]
-    if kind == "qdwconv":
-        macs = oh * ow * cout * k * k
-    else:
-        macs = oh * ow * cout * k * k * in_shape[-1]
-    act = math.prod(in_shape) + math.prod(out_shape)      # int8: 1 B each
-    return 2 * macs * lanes, act * lanes + int(attrs["weight_q"].nbytes)
+def rule(kind: str) -> Optional[ModuleType]:
+    """The rule file of an operator kind, or ``None`` where it has none."""
+    path = KERNELS / f"{kind}.py"
+    if not path.is_file():
+        return None
+    return loader.load(path, "kernel")
 
 
 def kernel_calls(graph, schedule, lanes: int) -> Dict[str, List[Call]]:
     """Kernel name -> the calls one dispatch makes, in schedule order."""
+    rules: Dict[str, Optional[ModuleType]] = {}
     out: Dict[str, List[Call]] = {}
     for op in schedule:
-        if op.kind not in ("qconv", "qdwconv"):
+        if op.kind not in rules:
+            rules[op.kind] = rule(op.kind)
+        r = rules[op.kind]
+        if r is None:
             continue
-        name = kernel_of(op.kind, op.attrs)
-        out.setdefault(name, []).append(call_cost(
-            op.kind, op.attrs, tuple(graph.tensors[op.inputs[0]].shape),
-            tuple(graph.tensors[op.output].shape), lanes))
+        in_shapes = tuple(tuple(graph.tensors[t].shape) for t in op.inputs)
+        out.setdefault(r.kernel_of(op.attrs), []).append(r.call_cost(
+            op.attrs, in_shapes, tuple(graph.tensors[op.output].shape),
+            lanes))
     return out
 
 

@@ -9,20 +9,21 @@ from __future__ import annotations
 
 import dataclasses
 import gc
-import importlib.util
 import json
 import math
 import sys
 import tempfile
 import time
 from pathlib import Path
+from types import ModuleType
 from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
-from . import counting, reference, trace as tracing, traffic
+from . import counting, loader, reference, trace as tracing, traffic
 
 HERE = Path(__file__).resolve().parents[1]          # chipbench/
+MODELS = HERE / "models"
 # a traced run's window: traces of the arena programs are large and slow
 # to read, and tracing slows the host, so per-layer metrics come from a
 # few seconds of their own
@@ -36,6 +37,7 @@ class Cell:
     name: str
     chips: int
     config: dict
+    model: ModuleType           # models/<config's model>.py
     mix: dict
     end_to_end: List[dict]
     per_layer: List[dict]
@@ -45,7 +47,21 @@ class Cell:
         return self.mix["kind"] == "open_loop"
 
 
-def load_cell(root: Path, workload: str) -> Cell:
+def load_model(name: str, models: Path = MODELS) -> ModuleType:
+    """The model file ``<models>/<name>.py``.  It gives what the harness
+    takes from a model: ``program_graph(cfg)``, the program's graph and
+    the only call into the program; ``quantize_model(cfg, *,
+    weight_bits=8)``, whose result has ``quantize_input(images)``;
+    ``int8_forward(qm)``, the plain reference's jitted int8 forward; and
+    ``model_macs(cfg)``, multiply-accumulates of one image."""
+    path = models / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no model {name!r}: {models} holds no "
+                                f"{name}.py")
+    return loader.load(path, "model")
+
+
+def load_cell(root: Path, workload: str, *, models: Path = MODELS) -> Cell:
     bench = json.loads((root / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in bench["workloads"]}
     if workload not in cells:
@@ -58,7 +74,8 @@ def load_cell(root: Path, workload: str) -> Cell:
 
     def mine(ms):
         return [m for m in ms if workload in m.get("workloads", [workload])]
-    return Cell(workload, int(w["chips"]), config, mix,
+    return Cell(workload, int(w["chips"]), config,
+                load_model(config["model"], models), mix,
                 mine(bench["end_to_end"]), mine(bench["per_layer"]))
 
 
@@ -76,12 +93,7 @@ class RunData:
 
 
 def reader(name: str) -> Callable[[RunData], Optional[float]]:
-    path = HERE / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return loader.load(HERE / "metrics" / f"{name}.py", "metric").read
 
 
 def log(msg: str) -> None:
@@ -92,16 +104,15 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
         devices: list, peaks: dict, t_start: float) -> Dict[str, Any]:
     import jax
     import repro.deploy as deploy
-    from repro.graphs import mobilenet_v1_graph
 
-    cfg, mix = cell.config, cell.mix
+    cfg, mix, model = cell.config, cell.mix, cell.model
     lanes = int(cfg["lanes"])
     dev = devices[0]
 
     # ------------------------------------------------------------ set-up
     t = time.perf_counter()
-    d = deploy.build(mobilenet_v1_graph(cfg["alpha"], cfg["resolution"]),
-                     quantize=True, arena_budget=cfg["arena_budget_bytes"],
+    d = deploy.build(model.program_graph(cfg), quantize=True,
+                     arena_budget=cfg["arena_budget_bytes"],
                      use_pallas=True, strict=True)
     build_s = time.perf_counter() - t
     if d.degraded or d.executor.device != dev:
@@ -175,8 +186,9 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
 
     # ------------------------------------------------------------ check
     t = time.perf_counter()
-    qm = reference.quantize_model(cfg)
-    want = reference.logits(qm, qm.quantize_input(images))
+    qm = model.quantize_model(cfg)
+    want = reference.logits(model.int8_forward(qm),
+                            qm.quantize_input(images))
     nums = reference.compare(served.answers, want[served.pool_index])
     log(f"[check] reference_s={time.perf_counter() - t} images={len(images)}")
     failed = sum(a is None for a in served.answers)
@@ -189,7 +201,7 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
 
     data = RunData(cell, {"setup_s": setup_s, "build_s": build_s,
                           "compile_s": compile_s},
-                   served, tr, calls, peaks, reference.model_macs(cfg))
+                   served, tr, calls, peaks, model.model_macs(cfg))
     metrics = {}
     for m in (cell.per_layer if trace else cell.end_to_end):
         v = reader(m["name"])(data)

@@ -1,151 +1,33 @@
-"""Plain int8 MobileNet-v1: the reference that decides ``correct``.
+"""The int8 arithmetic every model's reference shares, and the comparison
+that decides ``correct``.
 
-Written from the configuration alone, in straightforward ``jax.numpy``:
-no schedule, no arena, no Pallas, nothing imported from the program under
-test.  It rebuilds the model the configuration states:
+A model's reference (its layers, weight naming, calibration, quantization
+and int8 forward) lives in ``models/<model>.py``, loaded by the
+configuration's ``model`` key (``harness.load_model``).  This file holds
+what any int8 model shares, in plain NumPy and ``jax.numpy`` with nothing
+imported from the program under test:
 
-* float weights drawn per tensor name (``weight_init`` in the
-  configuration: a NumPy generator seeded with the CRC-32 of the name,
-  standard normals times a scale);
-* post-training quantization (``ptq``): activation ranges observed by one
-  float forward pass over a calibration image, asymmetric int8 activations
-  whose range includes 0, symmetric per-tensor int8 weights, pooling
-  passing its input's parameters through;
-* int8 inference: int32 accumulation of ``(x - zp_in) * w`` with SAME
-  padding, one float32 requantizing multiply, round half to even, the
-  fused ReLU as a lower clamp at the output zero point.
-
-The calibration pass runs eagerly, one primitive at a time, on the default
-device at its default matmul precision, and sums the head's products in a
-fixed pairwise order: the activation scales are a function of that float
-arithmetic, and the configuration states it so that the scales are exact.
-
-``weight_bits=4`` gives the control: the same network with int4 weights.
+* ``QP`` and ``activation_qp``: asymmetric per-tensor int8 activations
+  whose range includes 0; ``quantize``: float images to int8 at the edge;
+* ``quantize_weight``: symmetric per-tensor weights in ``[-w, w]``,
+  ``w = 2**(bits-1) - 1`` (``bits=4`` is the control);
+* ``same_pads``: TensorFlow's SAME padding;
+* ``requantize``: an int32 accumulator to int8 by one float32 multiply,
+  round half to even, and a clamp (a fused ReLU clamps at the output zero
+  point);
+* ``logits``: a model's int8 forward over quantized images, in blocks;
+* ``compare``: the exact comparison of served logits with the reference's.
 """
 from __future__ import annotations
 
 import dataclasses
-import zlib
-from typing import Dict, List, Optional, Sequence
+from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
-import jax
 import jax.numpy as jnp
-from jax import lax
 
 QMIN, QMAX = -128, 127
-_DN = ("NHWC", "HWIO", "NHWC")
-
-
-@dataclasses.dataclass(frozen=True)
-class Layer:
-    kind: str            # conv | dwconv | avgpool | fc
-    weight: str          # weight tensor name ("" for avgpool)
-    k: int
-    stride: int
-    cin: int
-    cout: int
-    h_out: int           # square outputs: height == width
-
-    @property
-    def macs(self) -> int:
-        """Multiply-accumulates of one image through this layer."""
-        hw = self.h_out * self.h_out
-        if self.kind == "conv":
-            return hw * self.cout * self.k * self.k * self.cin
-        if self.kind == "dwconv":
-            return hw * self.cout * self.k * self.k
-        if self.kind == "fc":
-            return self.cin * self.cout
-        return 0
-
-
-def layers(cfg: dict) -> List[Layer]:
-    """MobileNet-v1's layers at the configuration's width and resolution,
-    with each weight's name as the configuration's naming rule gives it:
-    operators are counted from 1 in order, and a weight is named after the
-    operator's kind prefix and number."""
-    alpha, h = cfg["alpha"], cfg["resolution"]
-    names = cfg["weight_init"]["names"]
-    out: List[Layer] = []
-
-    def add(kind, k, stride, cin, cout):
-        nonlocal h
-        h_out = -(-h // stride)
-        n = len(out) + 1
-        out.append(Layer(kind, names[kind].format(n=n) if kind in names
-                         else "", k, stride, cin, cout, h_out))
-        h = h_out
-
-    c = cfg["input_channels"]
-    stem = int(cfg["stem_channels"] * alpha)
-    add("conv", 3, 2, c, stem)
-    c = stem
-    for stride, cout in cfg["blocks"]:
-        add("dwconv", 3, stride, c, c)
-        add("conv", 1, 1, c, int(cout * alpha))
-        c = int(cout * alpha)
-    add("avgpool", h, h, c, c)
-    add("fc", 1, 1, c, cfg["num_classes"])
-    return out
-
-
-def model_macs(cfg: dict) -> int:
-    """Multiply-accumulates of one image through the unrewritten graph."""
-    return sum(layer.macs for layer in layers(cfg))
-
-
-def float_weight(cfg: dict, layer: Layer) -> np.ndarray:
-    init = cfg["weight_init"]
-    if layer.kind == "conv":
-        shape = (layer.k, layer.k, layer.cin, layer.cout)
-    elif layer.kind == "dwconv":
-        shape = (layer.k, layer.k, layer.cin, 1)
-    else:
-        shape = (layer.cin, layer.cout)
-    rng = np.random.default_rng(zlib.crc32(layer.weight.encode()))
-    return (rng.standard_normal(shape) * init["scale"]).astype(np.float32)
-
-
-def calibration_image(cfg: dict) -> np.ndarray:
-    r = cfg["resolution"]
-    rng = np.random.default_rng(cfg["ptq"]["calibration_seed"])
-    return rng.standard_normal((r, r, cfg["input_channels"])
-                               ).astype(np.float32)
-
-
-def same_pads(n: int, k: int, stride: int):
-    """(begin, end) of TensorFlow's SAME padding along one axis."""
-    out = -(-n // stride)
-    total = max((out - 1) * stride + k - n, 0)
-    return total // 2, total - total // 2
-
-
-# ------------------------------------------------------------- calibration
-def _float_layer(layer: Layer, x: np.ndarray, w: np.ndarray) -> np.ndarray:
-    """One layer of the float network on one (H, W, C) image, eagerly."""
-    if layer.kind in ("conv", "dwconv"):
-        p = same_pads(x.shape[0], layer.k, layer.stride)
-        kw = {}
-        if layer.kind == "dwconv":
-            w = jnp.reshape(jnp.transpose(w, (0, 1, 3, 2)),
-                            (layer.k, layer.k, 1, layer.cin))
-            kw = dict(feature_group_count=layer.cin)
-        y = lax.conv_general_dilated(
-            x[None], w, window_strides=(layer.stride, layer.stride),
-            padding=[p, p], dimension_numbers=_DN, **kw)[0]
-        return np.asarray(jnp.maximum(y, 0.0))
-    if layer.kind == "avgpool":
-        return np.asarray(jnp.mean(x, axis=(0, 1), keepdims=True))
-    # fc: the products, then a fixed pairwise tree of adds
-    p = jnp.reshape(x, (-1, 1)) * w
-    while p.shape[0] > 1:
-        h = p.shape[0] // 2
-        top = p[:h] + p[h:2 * h]
-        p = top if p.shape[0] % 2 == 0 else jnp.concatenate(
-            [top, p[2 * h:]], axis=0)
-    return np.asarray(p[0])[None, None, :]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -161,95 +43,38 @@ def activation_qp(lo: float, hi: float) -> QP:
     return QP(scale, max(QMIN, min(QMAX, zp)))
 
 
-@dataclasses.dataclass
-class QuantModel:
-    """Everything the int8 forward needs, derived from the configuration."""
-
-    layers: List[Layer]
-    act: List[QP]                 # [input, output of layer 0, 1, ...]
-    weights: List[Optional[np.ndarray]]
-    mults: List[Optional[float]]
-
-    def quantize_input(self, images: np.ndarray) -> np.ndarray:
-        qp = self.act[0]
-        q = np.round(np.asarray(images, np.float32) / np.float32(qp.scale))
-        return np.clip(q + qp.zp, QMIN, QMAX).astype(np.int8)
+def quantize(x: np.ndarray, qp: QP) -> np.ndarray:
+    """Float values to int8 with ``qp``, as the edge quantizer does."""
+    q = np.round(np.asarray(x, np.float32) / np.float32(qp.scale))
+    return np.clip(q + qp.zp, QMIN, QMAX).astype(np.int8)
 
 
-def quantize_model(cfg: dict, *, weight_bits: int = 8) -> QuantModel:
-    """Calibrate on the configuration's image, then quantize."""
-    ls = layers(cfg)
-    fw = [float_weight(cfg, layer) if layer.weight else None for layer in ls]
-    x = calibration_image(cfg)
-    ranges = [(float(np.min(x)), float(np.max(x)))]
-    for layer, w in zip(ls, fw):
-        x = _float_layer(layer, x, w)
-        ranges.append((float(np.min(x)), float(np.max(x))))
-    act = [activation_qp(*r) for r in ranges]
-    for i, layer in enumerate(ls):
-        if layer.kind == "avgpool":     # pooling keeps its input's params
-            act[i + 1] = act[i]
-    wmax = 2 ** (weight_bits - 1) - 1
-    weights: List[Optional[np.ndarray]] = []
-    mults: List[Optional[float]] = []
-    for i, (layer, w) in enumerate(zip(ls, fw)):
-        if w is None:
-            weights.append(None)
-            mults.append(None)
-            continue
-        sw = float(max(np.abs(w).max(), 1e-8)) / wmax
-        wq = np.clip(np.round(w / np.float32(sw)), -wmax, wmax)
-        weights.append(wq.astype(np.int8))
-        mults.append(act[i].scale * sw / act[i + 1].scale)
-    return QuantModel(ls, act, weights, mults)
+def quantize_weight(w: np.ndarray, bits: int) -> Tuple[np.ndarray, float]:
+    """Symmetric per-tensor weights: the integers (held as int8) and the
+    scale."""
+    wmax = 2 ** (bits - 1) - 1
+    sw = float(max(np.abs(w).max(), 1e-8)) / wmax
+    wq = np.clip(np.round(w / np.float32(sw)), -wmax, wmax)
+    return wq.astype(np.int8), sw
 
 
-# ------------------------------------------------------------ int8 forward
-def _requant(acc, mult: float, zp: int, lo: int):
+def same_pads(n: int, k: int, stride: int):
+    """(begin, end) of TensorFlow's SAME padding along one axis."""
+    out = -(-n // stride)
+    total = max((out - 1) * stride + k - n, 0)
+    return total // 2, total - total // 2
+
+
+def requantize(acc, mult: float, zp: int, lo: int):
     y = jnp.round(acc.astype(jnp.float32) * jnp.float32(mult)) + zp
     return jnp.clip(y, lo, QMAX).astype(jnp.int8)
 
 
-def int8_forward(qm: QuantModel):
-    """``(N, H, W, C) int8 -> (N, 1, 1, classes) int8``, jitted."""
-    ls, act, ws, mults = qm.layers, qm.act, qm.weights, qm.mults
-
-    def fwd(x):
-        for i, layer in enumerate(ls):
-            qin, qout = act[i], act[i + 1]
-            if layer.kind in ("conv", "dwconv"):
-                w = jnp.asarray(ws[i], jnp.int32)
-                kw = {}
-                if layer.kind == "dwconv":
-                    w = jnp.reshape(jnp.transpose(w, (0, 1, 3, 2)),
-                                    (layer.k, layer.k, 1, layer.cin))
-                    kw = dict(feature_group_count=layer.cin)
-                p = same_pads(x.shape[1], layer.k, layer.stride)
-                acc = lax.conv_general_dilated(
-                    x.astype(jnp.int32) - qin.zp, w,
-                    window_strides=(layer.stride, layer.stride),
-                    padding=[p, p], dimension_numbers=_DN,
-                    preferred_element_type=jnp.int32, **kw)
-                x = _requant(acc, mults[i], qout.zp, lo=qout.zp)
-            elif layer.kind == "avgpool":
-                m = jnp.mean(x.astype(jnp.float32), axis=(1, 2),
-                             keepdims=True)
-                x = jnp.clip(jnp.round(m), QMIN, QMAX).astype(jnp.int8)
-            else:
-                xi = jnp.reshape(x.astype(jnp.int32) - qin.zp,
-                                 (x.shape[0], -1, 1))
-                acc = jnp.sum(xi * jnp.asarray(ws[i], jnp.int32), axis=1)
-                x = _requant(acc, mults[i], qout.zp, lo=QMIN)[:, None, None]
-        return x
-
-    return jax.jit(fwd)
-
-
-def logits(qm: QuantModel, images_q: np.ndarray, *,
+def logits(forward: Callable, images_q: np.ndarray, *,
            block: int = 32) -> np.ndarray:
-    """int8 logits ``(N, classes)`` of quantized images, ``block`` images
-    per call so that the int32 activations fit beside the program."""
-    fwd = int8_forward(qm)
+    """int8 logits ``(N, classes)`` of quantized images by a model's
+    jitted ``forward``, ``block`` images per call so that the int32
+    activations fit beside the program."""
     out = []
     for i in range(0, len(images_q), block):
         part = images_q[i:i + block]
@@ -257,7 +82,7 @@ def logits(qm: QuantModel, images_q: np.ndarray, *,
         if pad:                    # one compiled shape for every block
             part = np.concatenate([part, np.zeros((pad,) + part.shape[1:],
                                                   part.dtype)])
-        y = np.asarray(fwd(part)).reshape(block, -1)
+        y = np.asarray(forward(part)).reshape(block, -1)
         out.append(y[:block - pad])
     return np.concatenate(out)
 

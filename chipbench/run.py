@@ -1,20 +1,22 @@
-"""Chip benchmark of served int8 MobileNet deployments on TPU.
+"""Chip benchmark of served int8 MCU deployments on TPU.
 
     python chipbench/run.py --workload <cell> --seed <n> --seconds <s> \
         --trace <0|1>
 
 Runs one cell of ``BENCHMARK.json`` on the machine it is started on: builds
-the cell's deployment through ``deploy.build``, warms its engine, drives it
-with the cell's traffic for ``--seconds``, checks every answer against the
-plain int8 reference (``lib/reference.py``), and prints one JSON object as
+the deployment of the configuration's model (``models/<model>.py``) through
+``deploy.build``, warms its engine, drives it with the cell's traffic for
+``--seconds``, checks every answer against that model's plain int8
+reference (with ``lib/reference.py``), and prints one JSON object as
 the last line of standard output.  With ``--trace 0`` the object holds the
 cell's end-to-end metrics; with ``--trace 1`` the window is traced and it
 holds the per-layer metrics, which the readers under ``metrics/`` take from
 the trace and the harness's own spans.
 
 It exits non-zero and prints no result when JAX finds no TPU, fewer chips
-than the cell asks for, or a device kind that ``peaks.json`` lacks, and
-when the program's sources are not in the checkout.
+than the cell asks for, or a device kind that ``peaks.json`` lacks, when
+the program's sources are not in the checkout, and (code 2) when the cell
+or its configuration's model file cannot be loaded.
 """
 from __future__ import annotations
 

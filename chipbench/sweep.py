@@ -4,9 +4,10 @@
     python chipbench/sweep.py --config mobilenet_v1_1.0_192_int8.reorder \
         --lanes 8,32,128 --rates 0.6,0.7,0.8,0.9,1.0 --rate-lanes 32
 
-One process builds the configuration's deployment once.  For each lane
-count it times the engine's construction and first dispatches (a cold
-compile where the compile cache is empty) and serves a backlog window;
+One process builds the configuration's deployment once, from the graph
+of its model (``models/<model>.py``).  For each lane count it times the
+engine's construction and first dispatches (a cold compile where the
+compile cache is empty) and serves a backlog window;
 then, at ``--rate-lanes``, it offers open-loop load at each fraction of
 that lane count's backlog throughput (the knee).  One JSON object per
 measurement goes to standard output.
@@ -45,17 +46,17 @@ def main(argv=None) -> int:
     enable_compile_cache()
     import jax
     import repro.deploy as deploy
-    from repro.graphs import mobilenet_v1_graph
-    from lib import traffic
+    from lib import harness, traffic
 
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         print("sweep: JAX found no TPU", file=sys.stderr)
         return 1
     cfg = json.loads((HERE / "configs" / f"{args.config}.json").read_text())
+    model = harness.load_model(cfg["model"])
     t = time.perf_counter()
-    d = deploy.build(mobilenet_v1_graph(cfg["alpha"], cfg["resolution"]),
-                     quantize=True, arena_budget=cfg["arena_budget_bytes"],
+    d = deploy.build(model.program_graph(cfg), quantize=True,
+                     arena_budget=cfg["arena_budget_bytes"],
                      use_pallas=True, strict=True)
     emit(config=args.config, build_s=time.perf_counter() - t,
          arena_bytes=d.arena_bytes, steps=len(d.schedule),

@@ -67,6 +67,24 @@ def test_command_refuses_without_program_sources(tmp_path):
     assert p.returncode != 0 and not p.stdout.strip()
 
 
+def test_command_refuses_an_unknown_model(tmp_path):
+    """A configuration whose model has no file under ``models/``: exit 2
+    at ``load_cell``, before JAX is asked for a chip."""
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    shutil.copytree(BENCH, tmp_path / "chipbench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (tmp_path / "src").symlink_to(ROOT / "src")
+    conf = tmp_path / "chipbench" / "configs" / \
+        "mobilenet_v1_1.0_192_int8.reorder.json"
+    config = json.loads(conf.read_text())
+    config["model"] = "no_such_model"
+    conf.write_text(json.dumps(config))
+    p = _cmd(tmp_path)
+    assert p.returncode == 2 and not p.stdout.strip()
+    assert "cannot load workload" in p.stderr
+    assert "no_such_model" in p.stderr
+
+
 def test_unknown_device_kind_is_refused():
     peaks = json.loads((BENCH / "peaks.json").read_text())
     assert "cpu" not in peaks
