@@ -282,7 +282,9 @@ def build(graph: Graph, *, arena_budget: Optional[int] = None,
     Each phase of an attempt reports its wall time as a ``jax.monitoring``
     duration event: ``/repro/deploy/quantize`` (``quantize=True`` only),
     ``/repro/deploy/schedule``, ``/repro/deploy/plan`` (plan and
-    validate) and ``/repro/deploy/lower``.
+    validate) and ``/repro/deploy/lower``.  The deployed schedule's
+    recomputed share of the graph's MACs (``extra_macs_frac``) is the
+    ``jax.monitoring`` scalar ``/repro/deploy/extra_macs_frac``.
     """
     qmodel = None
     if quantize:
@@ -329,6 +331,8 @@ def build(graph: Graph, *, arena_budget: Optional[int] = None,
             raise DeploymentError(
                 "every scheduler rung set failed — nothing left to degrade "
                 "to:\n  " + "\n  ".join(degraded))
+    jax.monitoring.record_scalar("/repro/deploy/extra_macs_frac",
+                                 float(res.extra_macs_frac))
     if arena_budget is not None and plan.arena_size > arena_budget:
         miss = (f"arena budget missed: need {int(plan.arena_size)} B > "
                 f"budget {int(arena_budget)} B (best rung: {res.method})")

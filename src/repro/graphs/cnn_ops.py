@@ -49,24 +49,27 @@ def _pads(n: int, k: int, stride: int) -> Tuple[int, int]:
 
 
 # ----------------------------------------------------- slice-spec factories
-def _windowed_slice_fn(kernel_name: str, attr_names: Tuple[str, ...]):
+def _windowed_slice_fn(kernel_name: str, attr_names: Tuple[str, ...],
+                       kw_names: Tuple[str, ...] = ()):
     """make_fn factory for windowed kernels: reads the kernel's extra args
-    from op.attrs and rebuilds it with explicit height padding — and, for
-    2-D tile clones, explicit width padding.  1-D callers pass two pads and
-    get the legacy closure (no ``wpad`` argument at all), so the row-ring
-    path traces byte-identical jaxprs."""
+    from op.attrs (``kw_names`` by keyword, where the op has them) and
+    rebuilds it with explicit height padding — and, for 2-D tile clones,
+    explicit width padding.  1-D callers pass two pads and get the legacy
+    closure (no ``wpad`` argument at all), so the row-ring path traces
+    byte-identical jaxprs."""
     def make(op: Operator, pad_top: int, pad_bottom: int,
              pad_left: Optional[int] = None, pad_right: Optional[int] = None):
         kernel = globals()[kernel_name]
         args = tuple(op.attrs[a] for a in attr_names)
+        kw = {a: op.attrs[a] for a in kw_names if a in op.attrs}
 
         if pad_left is None:
             def fn(x, kernel=kernel, args=args, hpad=(pad_top, pad_bottom)):
-                return kernel(x, *args, hpad=hpad)
+                return kernel(x, *args, hpad=hpad, **kw)
         else:
             def fn(x, kernel=kernel, args=args, hpad=(pad_top, pad_bottom),
                    wpad=(pad_left, pad_right)):
-                return kernel(x, *args, hpad=hpad, wpad=wpad)
+                return kernel(x, *args, hpad=hpad, wpad=wpad, **kw)
         return fn
     return make
 
@@ -90,11 +93,13 @@ def pex_spec(kind: str, out_shape: Tuple[int, int, int], cin: int,
     oh, ow, cout = out_shape
     if kind == "conv":
         return SliceSpec(k, stride, (0,),
-                         _windowed_slice_fn("conv2d", ("weight", "stride")),
+                         _windowed_slice_fn("conv2d", ("weight", "stride"),
+                                            ("act",)),
                          macs_per_row=ow * cout * k * k * cin)
     if kind == "dwconv":
         return SliceSpec(k, stride, (0,),
-                         _windowed_slice_fn("dwconv2d", ("weight", "stride")),
+                         _windowed_slice_fn("dwconv2d", ("weight", "stride"),
+                                            ("act",)),
                          macs_per_row=ow * cout * k * k)
     if kind == "maxpool":
         return SliceSpec(k, stride, (0,),
@@ -105,11 +110,13 @@ def pex_spec(kind: str, out_shape: Tuple[int, int, int], cin: int,
                          macs_per_row=ow * cout)
     if kind == "qconv":
         return SliceSpec(k, stride, (0,),
-                         _windowed_slice_fn("qconv2d", _QCONV_ATTRS),
+                         _windowed_slice_fn("qconv2d", _QCONV_ATTRS,
+                                            ("lo",)),
                          macs_per_row=ow * cout * k * k * cin)
     if kind == "qdwconv":
         return SliceSpec(k, stride, (0,),
-                         _windowed_slice_fn("qdwconv2d", _QCONV_ATTRS),
+                         _windowed_slice_fn("qdwconv2d", _QCONV_ATTRS,
+                                            ("lo",)),
                          macs_per_row=ow * cout * k * k)
     if kind == "qmaxpool":
         return SliceSpec(k, stride, (0,),
@@ -159,31 +166,37 @@ class CNNBuilder:
         self.g.add_operator(name, list(inputs), out, kind=kind, fn=fn, **attrs)
         return out
 
-    def conv(self, x: str, cout: int, k: int = 1, stride: int = 1) -> str:
+    def conv(self, x: str, cout: int, k: int = 1, stride: int = 1,
+             act: str = "relu") -> str:
+        """``act`` is the fused activation (``ACTS``): ReLU, ReLU6, or
+        none for a linear layer such as a bottleneck projection."""
+        _check_act(act)
         h, w, cin = self.shapes[x]
         oh, ow = conv_out_hw(h, w, stride)
         wname = f"conv{self._n + 1}_w"
         wgt = _weight(wname, (k, k, cin, cout))
 
-        def fn(a, w=wgt, stride=stride):
-            return conv2d(a, w, stride)
+        def fn(a, w=wgt, stride=stride, act=act):
+            return conv2d(a, w, stride, act=act)
 
         return self._emit("conv", [x], (oh, ow, cout), fn, cin=cin,
                           weight_bytes=wgt.nbytes, weight=wgt, k=k,
-                          stride=stride)
+                          stride=stride, act=act)
 
-    def dwconv(self, x: str, k: int = 3, stride: int = 1) -> str:
+    def dwconv(self, x: str, k: int = 3, stride: int = 1,
+               act: str = "relu") -> str:
+        _check_act(act)
         h, w, cin = self.shapes[x]
         oh, ow = conv_out_hw(h, w, stride)
         wname = f"dw{self._n + 1}_w"
         wgt = _weight(wname, (k, k, cin, 1))
 
-        def fn(a, w=wgt, stride=stride):
-            return dwconv2d(a, w, stride)
+        def fn(a, w=wgt, stride=stride, act=act):
+            return dwconv2d(a, w, stride, act=act)
 
         return self._emit("dwconv", [x], (oh, ow, cin), fn, cin=cin,
                           weight_bytes=wgt.nbytes, weight=wgt, k=k,
-                          stride=stride)
+                          stride=stride, act=act)
 
     def maxpool(self, x: str, k: int = 2, stride: int = 2) -> str:
         h, w, c = self.shapes[x]
@@ -247,9 +260,28 @@ def _tree_sum(p):
     return p[0]
 
 
+# Fused activations of conv/dwconv: ReLU, ReLU6 (MobileNet-v2's expansion
+# and depthwise layers), and none (its linear bottleneck projections).
+ACTS = ("relu", "relu6", "none")
+
+
+def _check_act(act: str) -> None:
+    if act not in ACTS:
+        raise ValueError(f"activation {act!r} is not one of {ACTS}")
+
+
+def _activate(y, act: str):
+    if act == "relu":
+        return jnp.maximum(y, 0.0)
+    if act == "relu6":
+        return jnp.clip(y, 0.0, 6.0)
+    _check_act(act)
+    return y
+
+
 def conv2d(x, w, stride: int, hpad: Optional[Tuple[int, int]] = None,
-           wpad: Optional[Tuple[int, int]] = None):
-    """x: (H,W,Cin) f32; w: (k,k,Cin,Cout); SAME padding; relu.
+           wpad: Optional[Tuple[int, int]] = None, act: str = "relu"):
+    """x: (H,W,Cin) f32; w: (k,k,Cin,Cout); SAME padding; then ``act``.
 
     ``hpad`` overrides the height padding with an explicit (top, bottom)
     pair — partial execution uses this to run a slice whose interior edges
@@ -264,11 +296,11 @@ def conv2d(x, w, stride: int, hpad: Optional[Tuple[int, int]] = None,
     y = lax.conv_general_dilated(
         x[None], w, window_strides=(stride, stride), padding=[hp, wp],
         dimension_numbers=("NHWC", "HWIO", "NHWC"))[0]
-    return jnp.maximum(y, 0.0)
+    return _activate(y, act)
 
 
 def dwconv2d(x, w, stride: int, hpad: Optional[Tuple[int, int]] = None,
-             wpad: Optional[Tuple[int, int]] = None):
+             wpad: Optional[Tuple[int, int]] = None, act: str = "relu"):
     cin = x.shape[-1]
     k = w.shape[0]
     hp = _pads(x.shape[0], k, stride) if hpad is None else tuple(hpad)
@@ -278,7 +310,7 @@ def dwconv2d(x, w, stride: int, hpad: Optional[Tuple[int, int]] = None,
         window_strides=(stride, stride), padding=[hp, wp],
         feature_group_count=cin,
         dimension_numbers=("NHWC", "HWIO", "NHWC"))[0]
-    return jnp.maximum(y, 0.0)
+    return _activate(y, act)
 
 
 def maxpool2d(x, k: int, stride: int,
@@ -311,7 +343,13 @@ INT8_MIN, INT8_MAX = -128, 127
 
 def requantize(acc, mult: float, zp_out: int, lo: int = INT8_MIN):
     """int32 accumulator -> int8 at the output (scale, zero_point).  ``lo``
-    is the lower clamp: ``zp_out`` for fused relu (real 0), -128 otherwise."""
+    is the lower clamp: ``zp_out`` for fused relu (real 0), -128 otherwise.
+
+    There is no upper clamp but 127.  A ReLU6 output is calibrated through
+    its clip at 6, so its range is [0, m] with m <= 6: ``s_out = m / 255``
+    and ``zp_out = -128``, and real 6 sits at ``zp_out + round(6 / s_out)
+    >= 127``.  The int8 ReLU6 is therefore the clamp ``[zp_out, 127]``,
+    the range TFLite's activation rule gives it (``activation_lo``)."""
     y = jnp.round(acc.astype(jnp.float32) * jnp.float32(mult)) + zp_out
     return jnp.clip(y, lo, INT8_MAX).astype(jnp.int8)
 
@@ -328,11 +366,20 @@ def dequantize_array(q, scale: float, zp: int):
     return (q.astype(jnp.float32) - zp) * jnp.float32(scale)
 
 
+def activation_lo(act: str, zp_out: int) -> int:
+    """The int8 lower clamp of a fused activation: ``zp_out`` (real 0) for
+    ReLU and ReLU6, -128 for a linear layer (see ``requantize``)."""
+    _check_act(act)
+    return INT8_MIN if act == "none" else zp_out
+
+
 def qconv2d(x, w, stride: int, mult: float, zp_in: int, zp_out: int,
             hpad: Optional[Tuple[int, int]] = None,
-            wpad: Optional[Tuple[int, int]] = None):
-    """x: (H,W,Cin) int8; w: (k,k,Cin,Cout) int8; SAME padding; fused relu
-    (lower clamp at ``zp_out``).  ``hpad``/``wpad`` as in ``conv2d``."""
+            wpad: Optional[Tuple[int, int]] = None,
+            lo: Optional[int] = None):
+    """x: (H,W,Cin) int8; w: (k,k,Cin,Cout) int8; SAME padding; lower clamp
+    ``lo`` (default ``zp_out``: fused relu; ``activation_lo``).
+    ``hpad``/``wpad`` as in ``conv2d``."""
     k = w.shape[0]
     hp = _pads(x.shape[0], k, stride) if hpad is None else tuple(hpad)
     wp = _pads(x.shape[1], w.shape[1], stride) if wpad is None else tuple(wpad)
@@ -340,12 +387,13 @@ def qconv2d(x, w, stride: int, mult: float, zp_in: int, zp_out: int,
     acc = lax.conv_general_dilated(
         xi[None], jnp.asarray(w, jnp.int32), window_strides=(stride, stride),
         padding=[hp, wp], dimension_numbers=("NHWC", "HWIO", "NHWC"))[0]
-    return requantize(acc, mult, zp_out, lo=zp_out)
+    return requantize(acc, mult, zp_out, lo=zp_out if lo is None else lo)
 
 
 def qdwconv2d(x, w, stride: int, mult: float, zp_in: int, zp_out: int,
               hpad: Optional[Tuple[int, int]] = None,
-              wpad: Optional[Tuple[int, int]] = None):
+              wpad: Optional[Tuple[int, int]] = None,
+              lo: Optional[int] = None):
     cin = x.shape[-1]
     k = w.shape[0]
     hp = _pads(x.shape[0], k, stride) if hpad is None else tuple(hpad)
@@ -357,7 +405,7 @@ def qdwconv2d(x, w, stride: int, mult: float, zp_in: int, zp_out: int,
         xi[None], wi, window_strides=(stride, stride), padding=[hp, wp],
         feature_group_count=cin,
         dimension_numbers=("NHWC", "HWIO", "NHWC"))[0]
-    return requantize(acc, mult, zp_out, lo=zp_out)
+    return requantize(acc, mult, zp_out, lo=zp_out if lo is None else lo)
 
 
 def qmaxpool2d(x, k: int, stride: int,
@@ -481,16 +529,16 @@ def _rf_rebuild(graph: Graph, op: Operator, new_w: Optional[np.ndarray],
     fn = None
     if new_w is not None and op.fn is not None:
         if op.kind == "conv":
-            def fn(a, w=new_w, s=stride):
-                return conv2d(a, w, s)
+            def fn(a, w=new_w, s=stride, act=attrs.get("act", "relu")):
+                return conv2d(a, w, s, act=act)
         elif op.kind == "dwconv":
-            def fn(a, w=new_w, s=stride):
-                return dwconv2d(a, w, s)
+            def fn(a, w=new_w, s=stride, act=attrs.get("act", "relu")):
+                return dwconv2d(a, w, s, act=act)
         else:
             kern = qconv2d if op.kind == "qconv" else qdwconv2d
             def fn(a, kern=kern, w=new_w, at=dict(attrs)):
                 return kern(a, w, at["stride"], at["mult"], at["zp_in"],
-                            at["zp_out"])
+                            at["zp_out"], lo=at.get("lo"))
     new = Graph()
     for tname, t in graph.tensors.items():
         new.add_tensor(tname, t.size, t.shape, t.dtype)
@@ -585,20 +633,23 @@ from repro.mcu.compile import register_lowering
 @register_lowering("conv")
 def _lower_conv(ctx, op: Operator, x):
     w, stride = op.attrs["weight"], op.attrs["stride"]
+    act = op.attrs.get("act", "relu")
     if (ctx.use_pallas and op.attrs.get("k", 1) == 1 and stride == 1
             and x.ndim == 3):
         from repro.kernels import conv1x1_fused
-        return conv1x1_fused(x, jnp.asarray(w)[0, 0], relu=True,
-                             interpret=ctx.interpret)
+        y = conv1x1_fused(x, jnp.asarray(w)[0, 0], relu=act == "relu",
+                          interpret=ctx.interpret)
+        return _activate(y, act) if act == "relu6" else y
     return conv2d(x, w, stride, hpad=op.attrs.get("pex_pads"),
-                  wpad=op.attrs.get("pex_wpads"))
+                  wpad=op.attrs.get("pex_wpads"), act=act)
 
 
 @register_lowering("dwconv")
 def _lower_dwconv(ctx, op: Operator, x):
     return dwconv2d(x, op.attrs["weight"], op.attrs["stride"],
                     hpad=op.attrs.get("pex_pads"),
-                    wpad=op.attrs.get("pex_wpads"))
+                    wpad=op.attrs.get("pex_wpads"),
+                    act=op.attrs.get("act", "relu"))
 
 
 @register_lowering("maxpool")
@@ -621,12 +672,12 @@ def _lower_qconv(ctx, op: Operator, x):
         from repro.kernels import qconv_fused
         return qconv_fused(x, jnp.asarray(a["weight_q"]), stride=a["stride"],
                            mult=a["mult"], zp_in=a["zp_in"],
-                           zp_out=a["zp_out"],
+                           zp_out=a["zp_out"], lo=a.get("lo"),
                            hpad=None if hpad is None else tuple(hpad),
                            wpad=None if wpad is None else tuple(wpad),
                            interpret=ctx.interpret)
     return qconv2d(x, a["weight_q"], a["stride"], a["mult"], a["zp_in"],
-                   a["zp_out"], hpad=hpad, wpad=wpad)
+                   a["zp_out"], hpad=hpad, wpad=wpad, lo=a.get("lo"))
 
 
 @register_lowering("qdwconv")
@@ -638,11 +689,12 @@ def _lower_qdwconv(ctx, op: Operator, x):
         return qdwconv_fused(x, jnp.asarray(a["weight_q"]),
                              stride=a["stride"], mult=a["mult"],
                              zp_in=a["zp_in"], zp_out=a["zp_out"],
+                             lo=a.get("lo"),
                              hpad=None if hpad is None else tuple(hpad),
                              wpad=None if wpad is None else tuple(wpad),
                              interpret=ctx.interpret)
     return qdwconv2d(x, a["weight_q"], a["stride"], a["mult"], a["zp_in"],
-                     a["zp_out"], hpad=hpad, wpad=wpad)
+                     a["zp_out"], hpad=hpad, wpad=wpad, lo=a.get("lo"))
 
 
 @register_lowering("qmaxpool")
@@ -655,6 +707,12 @@ def _lower_qmaxpool(ctx, op: Operator, x):
 @register_lowering("qadd")
 def _lower_qadd(ctx, op: Operator, x, y):
     a = op.attrs
+    if ctx.use_pallas:
+        from repro.kernels import qadd_fused
+        return qadd_fused(x, y, add_params=(a["mult_a"], a["mult_b"],
+                                            a["zp_a"], a["zp_b"],
+                                            a["zp_out"]),
+                          interpret=ctx.interpret)
     return qadd(x, y, a["mult_a"], a["mult_b"], a["zp_a"], a["zp_b"],
                 a["zp_out"])
 

@@ -17,6 +17,12 @@ per-tensor activation ranges, and rewrites the graph with
   Pex slices (the q-kinds carry ``SliceSpec``s like their float
   counterparts).
 
+A conv's fused activation (its ``act`` attribute) becomes the int8 lower
+clamp ``lo`` (``cnn_ops.activation_lo``): ``zp_out`` for ReLU and ReLU6,
+-128 for a linear layer.  Calibration runs the float graph through the
+activation itself, so a ReLU6 output's range ends at or below 6 and the
+int8 ReLU6 needs no upper clamp but 127 (``cnn_ops.requantize``).
+
 Topology, tensor names and operator names are preserved, so any schedule
 found for the float graph maps 1:1, and the scheduling/partition machinery
 runs unchanged on the quantized graph — just over 4x smaller byte sizes.
@@ -198,14 +204,16 @@ def _quantize_op(old: Graph, new: Graph, op, qp: Dict[str, QParams]) -> None:
         s_in, zp_in = qp[ins[0]].scale, qp[ins[0]].zero_point
         s_out, zp_out = qp[out].scale, qp[out].zero_point
         mult = s_in * sw / s_out
+        act = op.attrs.get("act", "relu")
         attrs = dict(weight_q=wq, weight_bytes=wq.nbytes, k=op.attrs["k"],
                      stride=op.attrs["stride"], mult=mult, zp_in=zp_in,
-                     zp_out=zp_out)
+                     zp_out=zp_out, act=act,
+                     lo=cnn_ops.activation_lo(act, zp_out))
         kernel = cnn_ops.qconv2d if op.kind == "conv" else cnn_ops.qdwconv2d
 
         def fn(x, kernel=kernel, wq=wq, a=attrs):
             return kernel(x, wq, a["stride"], a["mult"], a["zp_in"],
-                          a["zp_out"])
+                          a["zp_out"], lo=a["lo"])
     elif op.kind == "maxpool":
         attrs = dict(k=op.attrs["k"], stride=op.attrs["stride"])
 

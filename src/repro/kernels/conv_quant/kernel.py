@@ -16,7 +16,9 @@ accumulator never round-trips through memory between the stages:
   output rows and accumulates k² (OW, Cin) @ (Cin, Cout) int8 contractions
   per output row;
 * ``qdwconv_pallas`` — depthwise: k² elementwise int32 multiply-accumulates
-  over the channel lane.
+  over the channel lane;
+* ``qadd_pallas`` — the residual add's fixed-point requantize
+  (``_qadd_replay``), elementwise, one block a lane.
 
 Zero points.  The MXU takes int8 operands, so the convs never widen ``x``
 to subtract ``zp_in`` first: they contract the raw int8 values and remove
@@ -111,6 +113,37 @@ def _qadd_replay(y, r, addp: AddParams):
     z = jnp.where(rem > half, base + 1,
                   jnp.where(rem < half, base, base + (base & 1)))
     return jnp.clip(z + zp_out, INT8_MIN, INT8_MAX).astype(jnp.int8)
+
+
+# ---------------------------------------------------------- residual add
+def _qadd_kernel(a_ref, b_ref, o_ref, *, addp: AddParams):
+    o_ref[...] = _qadd_replay(a_ref[...], b_ref[...], addp)
+
+
+def qadd_pallas(a: jax.Array, b: jax.Array, *, add_params: AddParams,
+                interpret: bool = False) -> jax.Array:
+    """The int8 residual add ``cnn_ops.qadd`` as one kernel: a, b [..., C]
+    int8 (same shape) -> int8, bit-identical.
+
+    Elementwise, so the layout is free: the operands are viewed as
+    lane-dense rows of 128 where their size allows and as (pixels, C)
+    otherwise, and each lane of a batch is one whole block (a residual of
+    a 512 KB arena is far below a core's VMEM)."""
+    _require_int8("a", a)
+    _require_int8("b", b)
+    if a.shape != b.shape:
+        raise ValueError(f"qadd operands differ in shape: {a.shape} vs "
+                         f"{b.shape}")
+    n = a.size
+    view = (n // 128, 128) if n % 128 == 0 else (n // a.shape[-1],
+                                                 a.shape[-1])
+    out = pl.pallas_call(
+        functools.partial(_qadd_kernel, addp=tuple(add_params)),
+        out_shape=jax.ShapeDtypeStruct(view, jnp.int8),
+        interpret=interpret,
+        name="qadd",
+    )(a.reshape(view), b.reshape(view))
+    return out.reshape(a.shape)
 
 
 # ------------------------------------------------------------- 1x1 pointwise

@@ -21,8 +21,10 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import ArenaPlanner, schedule
-from repro.graphs import mobilenet_v1_graph, quantize_graph, random_input
-from repro.kernels import qconv_add_fused, qconv_fused, qdwconv_fused
+from repro.graphs import (mobilenet_v1_graph, mobilenet_v2_graph,
+                          quantize_graph, random_input)
+from repro.kernels import (qadd_fused, qconv_add_fused, qconv_fused,
+                           qdwconv_fused)
 from repro.mcu import compile_schedule
 
 _QP = dict(mult=0.0123, zp_in=3, zp_out=-5)
@@ -86,6 +88,18 @@ _KERNELS = [
      dict(stride=1, hpad=(0, 0), wpad=(0, 0))),
     ("slice_pw_1x30x64_128", qconv_fused, [(1, 30, 64), (1, 1, 64, 128)],
      dict(stride=1, hpad=(0, 0), wpad=(0, 0))),
+    # MobileNet-v2 1.0@224 at the 512 KB rung: linear projections (lo =
+    # -128) and ReLU6 layers at its channel counts, whole and sliced
+    ("v2_project_56x144_24", qconv_fused, [(56, 56, 144), (1, 1, 144, 24)],
+     dict(stride=1, lo=-128)),
+    ("v2_project_7x960_320", qconv_fused, [(7, 7, 960), (1, 1, 960, 320)],
+     dict(stride=1, lo=-128)),
+    ("v2_expand_slice_3x112x16_96", qconv_fused,
+     [(3, 112, 16), (1, 1, 16, 96)], dict(stride=1, hpad=(0, 0))),
+    ("v2_dw_slice_5x112x96_s2", qdwconv_fused, [(5, 112, 96), (3, 3, 96, 1)],
+     dict(stride=2, hpad=(0, 0))),
+    ("v2_dw_14x576_s1", qdwconv_fused, [(14, 14, 576), (3, 3, 576, 1)],
+     dict(stride=1)),
 ]
 
 
@@ -95,6 +109,17 @@ def test_fused_kernel_compiles_for_v5e(v5e, name, op, shapes, opts):
     fn = jax.jit(functools.partial(op, interpret=False, **_QP, **opts))
     compiled = fn.lower(*[_int8(s, v5e) for s in shapes]).compile()
     assert "tpu_custom_call" in compiled.as_text(), name
+
+
+# MobileNet-v2 1.0@224's residual adds: whole (56x56x24 is lane-dense rows
+# of 128, 7x7x160 is not) and a row slice of the 512 KB rung
+@pytest.mark.parametrize("shape", [(56, 56, 24), (7, 7, 160), (2, 56, 24)],
+                         ids=lambda s: "x".join(map(str, s)))
+def test_qadd_compiles_for_v5e(v5e, shape):
+    fn = jax.jit(functools.partial(qadd_fused, add_params=_ADDP,
+                                   interpret=False))
+    compiled = fn.lower(_int8(shape, v5e), _int8(shape, v5e)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 def _mobilenet_025_int8():
@@ -123,3 +148,20 @@ def test_interpret_mode_refused_for_a_tpu_program(v5e):
     with pytest.raises(ValueError, match="interpret-mode"):
         compile_schedule(gq, sched, plan, use_pallas=True, interpret=True,
                          device=v5e)
+
+
+def test_batched_v2_arena_program_compiles_for_v5e(v5e):
+    """MobileNet-v2 0.35@32 sliced under 7,680 B: linear projections,
+    ReLU6 and residual adds, every one a Mosaic kernel in the vmapped
+    program."""
+    g = mobilenet_v2_graph(0.35, 32, 10)
+    gq = quantize_graph(g, random_input(g)).graph
+    res = schedule(gq, arena_budget=7680)
+    gp = res.graph
+    plan = ArenaPlanner.plan(gp, res.schedule)
+    ex = compile_schedule(gp, res.schedule, plan, use_pallas=True,
+                          device=v5e)
+    lanes = jax.ShapeDtypeStruct((8, ex.arena_size), jnp.uint8,
+                                 sharding=SingleDeviceSharding(v5e))
+    compiled = ex.batched_fn().jitted.lower(lanes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
